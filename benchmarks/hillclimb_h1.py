@@ -43,7 +43,7 @@ def lower_variant(tag, podwise, compression):
     cfg, run = get_config("minitron-8b")
     run = dataclasses.replace(run, grad_compression=compression)
     mesh = jax.make_mesh((2, 32), ("pod", "data"))
-    with SH.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         state_specs, batch = SPEC.input_specs(cfg, run, TRAIN_4K)
         state_sh = SH.make_state_shardings(mesh, state_specs, cfg, run)
         if podwise:

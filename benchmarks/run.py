@@ -62,6 +62,8 @@ def _flag_value(name):
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     quick = "--quick" in sys.argv
     json_path = _flag_value("--json")
     trace_path = _flag_value("--trace")

@@ -30,6 +30,7 @@ import tempfile
 import numpy as np
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import dwt2, idwt2, flatten_pyramid, unflatten_pyramid
 
 
@@ -162,6 +163,7 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--tiles", type=int, default=None, metavar="EDGE",
                     help="tile edge for the out-of-core streamed pipeline")

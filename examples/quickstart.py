@@ -6,6 +6,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from repro import engine as E
+from repro.compile_cache import enable_compile_cache
 from repro.core import dwt2, idwt2
 from repro.core import schemes as S
 from repro.core import optimize as O
@@ -20,6 +21,7 @@ def make_test_image(n=256):
 
 
 def main():
+    enable_compile_cache()
     img = make_test_image()
     print("image:", img.shape)
 

@@ -8,6 +8,7 @@ import dataclasses
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.base import ShapeConfig
 from repro.configs.registry import get_config
 from repro.data.pipeline import make_pipeline
@@ -30,6 +31,7 @@ def run_one(tag, cfg, run, steps_n, ckpt):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=120)
     args = ap.parse_args()

@@ -113,25 +113,28 @@ def conv_stats(specs: Sequence[ConvSpec]) -> dict:
 
 
 def _wrap_pad(x: jax.Array, rn: int, rm: int) -> jax.Array:
-    """Periodic pad of the two trailing axes by ``(rn, rm)``; mod-indexed
-    gather, so radii larger than the plane are fine (tiny odd shapes)."""
-    if rn:
-        n = x.shape[-2]
-        x = jnp.take(x, jnp.arange(-rn, n + rn) % n, axis=-2)
-    if rm:
-        m = x.shape[-1]
-        x = jnp.take(x, jnp.arange(-rm, m + rm) % m, axis=-1)
-    return x
+    """Periodic pad of the two trailing axes by ``(rn, rm)``; radii larger
+    than the plane are fine (tiny odd shapes).  Slices and concatenation,
+    not a gather: XLA:CPU in jax 0.9 miscompiles a mod-indexed
+    ``jnp.take`` feeding the conv (garbage output at e.g. 16x16 planes)."""
+    cfg = [(0, 0)] * (x.ndim - 2) + [(rn, rn), (rm, rm)]
+    return jnp.pad(x, cfg, mode="wrap")
 
 
 def _apply_conv(x: jax.Array, spec: ConvSpec) -> jax.Array:
-    """One grouped conv: (N, 4, h, w) -> (N, 4, h, w), periodic boundary."""
+    """One grouped conv: (N, 4, h, w) -> (N, 4, h, w), periodic boundary.
+
+    ``Precision.HIGHEST``: at the default precision the TPU's MXU rounds
+    float32 operands to bfloat16 (one pass), ~1e-3 relative error — three
+    orders of magnitude outside the cross-backend parity tolerance.  On
+    the CPU the flag changes nothing."""
     rn, rm = spec.pad
     xp = _wrap_pad(x, rn, rm)
     w = jnp.asarray(spec.weights, x.dtype)
     return jax.lax.conv_general_dilated(
         xp, w, window_strides=(1, 1), padding="VALID",
-        dimension_numbers=("NCHW", "OIHW", "NCHW"))
+        dimension_numbers=("NCHW", "OIHW", "NCHW"),
+        precision=jax.lax.Precision.HIGHEST)
 
 
 def run_planes_conv(programs: Sequence[ir.TapProgram], planes: Sequence,

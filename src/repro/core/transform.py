@@ -25,7 +25,8 @@ Parameters shared by :func:`dwt2` and :func:`idwt2`:
     (``repro.engine.available_backends()`` lists them).  Built-ins:
 
     * "jnp"     — pure-jnp reference (roll-based periodic convolution)
-    * "pallas"  — the TPU Pallas kernels (interpret=True on CPU)
+    * "pallas"  — the Pallas kernels (Mosaic on a TPU, the Pallas
+      interpreter on the CPU; refused on other platforms)
     * "xla"     — compiled tap programs as grouped
       ``lax.conv_general_dilated`` calls (one fused conv per step;
       GPU/TPU/CPU-portable, no Pallas dependency)
@@ -53,8 +54,10 @@ Parameters shared by :func:`dwt2` and :func:`idwt2`:
       pallas_call**: polyphase split/merge happens in-VMEM on
       compound-halo windows of the interleaved image and the LL plane
       never round-trips through HBM between levels (fewest bytes
-      moved).  Falls back to "levels" execution when the compound
-      window exceeds the VMEM budget (``$REPRO_PYRAMID_VMEM_LIMIT``);
+      moved).  CPU (interpreter) only: a TPU plan build refuses it,
+      since Mosaic does not lower its in-VMEM polyphase split.  Falls
+      back to "levels" execution when the compound window exceeds
+      the VMEM budget (``$REPRO_PYRAMID_VMEM_LIMIT``);
       on the jnp backend it runs the eager per-level chain,
       bit-identical to "none".
 ``boundary``

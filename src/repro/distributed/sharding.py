@@ -27,42 +27,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.configs.base import ModelConfig, RunConfig
 
 
-def use_mesh(mesh: Mesh):
-    """Version-compatible 'make this the ambient mesh' context manager.
-
-    ``jax.set_mesh`` only exists on newer jax; ``jax.sharding.use_mesh``
-    covers a middle band of versions; on older releases (e.g. 0.4.x) the
-    Mesh object itself is the context manager.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return mesh
-
-
-def shard_map(f, mesh: Mesh, in_specs, out_specs, manual_axes=None):
-    """Version-compatible shard_map with partially-manual axes.
-
-    Newer jax exposes ``jax.shard_map(..., axis_names=..., check_vma=...)``;
-    0.4.x has ``jax.experimental.shard_map.shard_map(..., auto=...,
-    check_rep=...)`` where ``auto`` is the complement of the manual set.
-    """
-    if hasattr(jax, "shard_map"):
-        kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-        if manual_axes is not None:
-            kwargs["axis_names"] = set(manual_axes)
-        try:
-            return jax.shard_map(f, check_vma=False, **kwargs)
-        except TypeError:  # older spelling of the replication check flag
-            return jax.shard_map(f, check_rep=False, **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    auto = (frozenset(mesh.axis_names) - frozenset(manual_axes)
-            if manual_axes is not None else frozenset())
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False, auto=auto)
-
-
 def make_tile_mesh(rows: int, cols: int,
                    axes: Tuple[str, str] = ("tr", "tc")) -> Mesh:
     """2-D device mesh for the tiling subsystem's shard_map transport:

@@ -21,8 +21,11 @@ Registered backends:
 
 * ``"jnp"``    — pure-jnp reference: periodic rolls over whole planes,
   broadcasts over batch dims; the numerics oracle.
-* ``"pallas"`` — TPU Pallas window kernels (interpret mode on CPU),
-  including the ``fuse="pyramid"`` megakernel.
+* ``"pallas"`` — Pallas window kernels: compiled by Mosaic on a TPU,
+  run by the Pallas interpreter on the CPU (tests), refused on any other
+  platform.  The ``fuse="pyramid"`` megakernel runs on the CPU only:
+  Mosaic does not lower its in-VMEM polyphase split, so a TPU plan
+  build rejects it.
 * ``"xla"``    — compiled tap programs lowered to grouped
   ``lax.conv_general_dilated`` calls over the polyphase planes
   (:mod:`repro.compiler.conv`): one fused conv per step, batched,
@@ -185,7 +188,7 @@ class Backend:
         if key.fuse == "levels":
             # one trace for the whole pyramid: levels chain without
             # returning to Python between them
-            return jax.jit(run)
+            return X.compiled_jit(run)
         if self.jit_per_level:
             # seed-granularity dispatch (one jitted call per level), but
             # with plan-resolved steps/blocks instead of per-call rebuilds
@@ -219,7 +222,7 @@ class Backend:
         if key.fuse == "pyramid":
             return self._pyramid_inverse(plan, run)
         if key.fuse == "levels":
-            return jax.jit(run)
+            return X.compiled_jit(run)
         if self.jit_per_level:
             fns = [self._jit_level(self.level_inverse, spec, key)
                    for spec in specs]
@@ -237,15 +240,15 @@ class Backend:
 
     @staticmethod
     def _jit_level(level_fn, spec, key):
-        return jax.jit(lambda v: level_fn(v, spec, key))
+        return X.compiled_jit(lambda v: level_fn(v, spec, key))
 
     def _pyramid_forward(self, plan, run):
         """fuse="pyramid" policy for backends without a megakernel:
         execute as fuse="levels" (single trace)."""
-        return jax.jit(run)
+        return X.compiled_jit(run)
 
     def _pyramid_inverse(self, plan, run):
-        return jax.jit(run)
+        return X.compiled_jit(run)
 
     def execute(self, plan, x):
         """Registry-level entry point: run ``plan`` forward on ``x``.
@@ -352,17 +355,35 @@ class JnpBackend(Backend):
 
 
 class PallasBackend(Backend):
-    """TPU Pallas window kernels (interpret mode on CPU): batch rides the
-    leading grid dimension, VMEM halo windows via double-buffered DMA;
-    ``fuse="pyramid"`` is the single-call megakernel."""
+    """Pallas window kernels: batch rides the leading grid dimension,
+    tile-aligned VMEM halo windows via double-buffered DMA.  Mosaic
+    compiles them on a TPU; on the CPU they run in the Pallas
+    interpreter (the test path).  ``fuse="pyramid"`` is the single-call
+    megakernel, which Mosaic does not lower yet: a TPU plan build
+    rejects it."""
 
     name = "pallas"
-    description = "TPU Pallas window kernels (interpret=True on CPU)"
+    description = ("Pallas window kernels (Mosaic on TPU, interpreter on "
+                   "CPU)")
     pyramid_kernel = True
     jit_per_level = True
     # capability-checked 3-D fallback: the window kernels dispatch per
     # level, so the jnp temporal pass runs unfused between them
     temporal_fuse = False
+
+    def validate(self, key) -> None:
+        super().validate(key)
+        platform = jax.default_backend()
+        if platform not in ("cpu", "tpu"):
+            raise BackendError(
+                f"PlanKey.backend='pallas' runs Mosaic kernels on a TPU "
+                f"and the Pallas interpreter on the CPU; platform "
+                f"{platform!r} has neither (use backend='xla')")
+        if key.fuse == "pyramid" and platform == "tpu":
+            raise BackendError(
+                "PlanKey.fuse='pyramid' does not compile for the TPU: "
+                "Mosaic does not lower the megakernel's in-VMEM "
+                "polyphase split; use fuse='levels'")
 
     def level_forward(self, x, spec, key):
         return X.pallas_level_forward(x, spec, key)
@@ -373,12 +394,13 @@ class PallasBackend(Backend):
     def _pyramid_forward(self, plan, run):
         if plan.pyramid is not None:
             return X.make_pyramid_forward(plan)
-        return jax.jit(run)    # VMEM-budget fallback: run as fuse="levels"
+        # VMEM-budget fallback: run as fuse="levels"
+        return X.compiled_jit(run)
 
     def _pyramid_inverse(self, plan, run):
         if plan.pyramid is not None:
             return X.make_pyramid_inverse(plan)
-        return jax.jit(run)
+        return X.compiled_jit(run)
 
     def launches(self, plan) -> int:
         if plan.key.fuse == "none":

@@ -30,7 +30,44 @@ from repro.kernels import polyphase as PP
 from repro.compiler import conv as CV
 from repro.compiler import execute as CX
 from repro import telemetry as T
+from repro.faults import degrade as DG
 from repro.faults import inject as FI
+
+
+def compiled_jit(fn):
+    """``jax.jit(fn)`` whose lowering and compilation are checked apart
+    from its execution.
+
+    The first call with each argument signature lowers and compiles
+    ahead of time; a failure there raises
+    :class:`~repro.faults.degrade.KernelCompileError`, which the
+    resilient dispatch propagates as a defect instead of serving the
+    plan on another backend.  The call itself then reuses that
+    executable (JAX shares the compilation between ``lower().compile()``
+    and the call).  Called under an outer trace, it traces inline.
+    """
+    jitted = jax.jit(fn)
+    checked = set()
+
+    def call(*args):
+        leaves, tree = jax.tree_util.tree_flatten(args)
+        if not any(isinstance(a, jax.core.Tracer) for a in leaves):
+            sig = (tree, tuple((jnp.shape(a), jnp.result_type(a),
+                                getattr(a, "sharding", None))
+                               for a in leaves))
+            if sig not in checked:
+                try:
+                    jitted.lower(*args).compile()
+                except FI.InjectedFault:
+                    raise
+                except Exception as e:
+                    raise DG.KernelCompileError(
+                        f"plan executor failed to lower or compile: "
+                        f"{type(e).__name__}: {e}") from e
+                checked.add(sig)
+        return jitted(*args)
+
+    return call
 
 
 def apply_steps_jnp(steps: Sequence[PP.StepSpec], planes: S.Planes
@@ -144,7 +181,7 @@ def _fuse_trace(plan, backend, run):
     trace under fuse="levels" when the backend allows it, else the
     eager per-node chain."""
     if plan.key.fuse == "levels" and backend.temporal_fuse:
-        return jax.jit(run)
+        return compiled_jit(run)
     return run
 
 
@@ -265,8 +302,8 @@ def make_pyramid_forward(plan):
     from repro.engine import plan as PLAN
     levels = plan.key.levels
     scheme = plan.key.scheme
-    fn = jax.jit(functools.partial(PP.pyramid_forward_pallas,
-                                   **_pyramid_kernel_kwargs(plan, False)))
+    fn = compiled_jit(functools.partial(
+        PP.pyramid_forward_pallas, **_pyramid_kernel_kwargs(plan, False)))
 
     def run(x):
         PLAN.PYRAMID_LAUNCHES.inc()
@@ -284,8 +321,8 @@ def make_pyramid_inverse(plan):
     from repro.engine import plan as PLAN
     levels = plan.key.levels
     scheme = plan.key.scheme
-    fn = jax.jit(functools.partial(PP.pyramid_inverse_pallas,
-                                   **_pyramid_kernel_kwargs(plan, True)))
+    fn = compiled_jit(functools.partial(
+        PP.pyramid_inverse_pallas, **_pyramid_kernel_kwargs(plan, True)))
 
     def run(ll, details):
         PLAN.PYRAMID_LAUNCHES.inc()

@@ -63,7 +63,12 @@ BOUNDARIES = ("periodic",)
 COMPUTE_DTYPES = ("float32", "bfloat16")
 
 PYRAMID_VMEM_LIMIT_ENV = "REPRO_PYRAMID_VMEM_LIMIT"
-DEFAULT_PYRAMID_VMEM_LIMIT = 12 * 2 ** 20  # of the ~16 MiB/core on TPU
+# budget for the rough estimate of PP.pyramid_vmem_bytes (I/O windows +
+# three compute windows).  Mosaic's real scoped VMEM runs ~5x over such
+# an estimate (cdf97 ns-conv window kernel, 128x512 block: ~3.8 MiB
+# estimated, 18.84 MiB compiled for a v5e), so 12 MiB stays inside the
+# PP.VMEM_LIMIT_BYTES (64 MiB) the kernels request.
+DEFAULT_PYRAMID_VMEM_LIMIT = 12 * 2 ** 20
 
 # engine-wide observability, on the central telemetry registry
 # (surfaced through repro.engine.stats() and the Prometheus exposition)
@@ -319,8 +324,9 @@ def _resolve_level(index: int, h: int, w: int, key: PlanKey,
                    block_target: Tuple[int, int],
                    backend: "B.Backend") -> LevelSpec:
     hp, wp = h // 2, w // 2
-    bh, hp2 = PP._pick_block(hp, block_target[0])
-    bw, wp2 = PP._pick_block(wp, block_target[1])
+    th, tw = PP.tile(key.dtype, PP._default_interpret())
+    bh, hp2 = PP._pick_block(hp, block_target[0], th)
+    bw, wp2 = PP._pick_block(wp, block_target[1], tw)
     fwd_programs = inv_programs = None
     # the backend decides the tap-program compilation level (None = raw
     # matrix walk) and the fuse granularity of its *launches*: one

@@ -15,6 +15,10 @@ system should exploit when a path *fails*, not just when it is slow.
    reference (the exactness contract) before accepting it;
 3. record every hop in ``repro_fallbacks_total{from, to, site}``.
 
+A plan whose own kernel fails to lower or compile
+(:class:`KernelCompileError`) is a defect, not a fault: it propagates
+at once, with no retry and no hop.
+
 Config via env (read once; :func:`reload` re-reads):
 
 * ``REPRO_RESILIENCE=on|off`` — ``off`` restores PR 8 behaviour
@@ -80,6 +84,14 @@ def reload() -> ResilienceConfig:
     global CONFIG
     CONFIG = _from_env()
     return CONFIG
+
+
+class KernelCompileError(RuntimeError):
+    """A plan's own executor failed to lower or compile (a Mosaic or XLA
+    compile error, a kernel over its VMEM budget).  That is a defect of
+    the plan, not a runtime fault: :func:`dispatch` neither retries nor
+    degrades it, so a plan never runs on another backend in its place.
+    The compiler's error is the ``__cause__``."""
 
 
 class ExactnessError(RuntimeError):
@@ -221,8 +233,9 @@ def dispatch(plan, op: str, args) -> object:
         return attempt()
     try:
         return retry_call(attempt, site=site, retries=cfg.retries,
-                          backoff_s=cfg.backoff_s)
-    except DeadlineExceeded:
+                          backoff_s=cfg.backoff_s,
+                          fatal=(KernelCompileError,))
+    except (DeadlineExceeded, KernelCompileError):
         raise
     except Exception as err:
         return _degrade(plan, op, args, err)

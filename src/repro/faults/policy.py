@@ -62,14 +62,16 @@ class Deadline:
 def retry_call(fn: Callable, *, site: str, retries: int = 2,
                backoff_s: float = 0.005, backoff_mult: float = 2.0,
                deadline: Optional[Deadline] = None,
-               retry_on: Tuple[Type[BaseException], ...] = (Exception,)):
+               retry_on: Tuple[Type[BaseException], ...] = (Exception,),
+               fatal: Tuple[Type[BaseException], ...] = ()):
     """Call ``fn()`` with up to ``retries`` recovery attempts.
 
     Backoff doubles per attempt (capped by the deadline's remaining
     budget); the *last* exception is re-raised when the budget is
     exhausted, so callers see the organic failure, not a wrapper.
-    ``DeadlineExceeded`` is never swallowed — a blown deadline must
-    propagate immediately rather than be retried into a longer stall.
+    ``DeadlineExceeded`` and the ``fatal`` types are never swallowed — a
+    blown deadline must propagate immediately rather than be retried
+    into a longer stall, and a defect cannot be retried away.
     """
     attempt = 0
     while True:
@@ -77,7 +79,7 @@ def retry_call(fn: Callable, *, site: str, retries: int = 2,
             if deadline is not None:
                 deadline.check(site)
             return fn()
-        except DeadlineExceeded:
+        except (DeadlineExceeded, *fatal):
             raise
         except retry_on:
             if attempt >= retries:

@@ -7,7 +7,7 @@ TPU adaptation of the paper's execution model (DESIGN.md §2):
   rides a leading grid dimension (one launch covers the whole batch);
 * GPU on-chip shared memory     ->  a VMEM scratch window per plane, filled
   by an explicit ``pltpu.make_async_copy`` DMA of the block + halo from a
-  wrap-padded HBM plane (inputs are kept in ``ANY`` memory space);
+  wrap-padded HBM plane (inputs stay in HBM; windows are whole tiles);
 * GPU threads                   ->  the 8x128 VPU vector lanes; every filter
   tap lowers to one shifted static slice + multiply-add over the whole
   block, so the per-pixel MAC count *is* the paper's operation count;
@@ -53,10 +53,37 @@ from repro.core import schemes as S
 from repro import compiler as C
 from repro.compiler import execute as CX
 
-# CPU containers run kernels through the interpreter; on real TPUs this
-# resolves to False and the Mosaic pipeline compiles the kernel.
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Kernels run through the Pallas interpreter on the CPU platform and
+    are compiled by Mosaic on a TPU.  Any other platform has no Pallas
+    TPU path: the pallas backend rejects it at plan build."""
+    return jax.default_backend() == "cpu"
+
+
+#: minor (lane) tile of a TPU array
+LANES = 128
+
+#: scoped VMEM each kernel may use.  Mosaic's default scope on a v5e is
+#: 16 MiB, which the larger tap programs overflow at the default block
+#: (cdf97 ns-conv needs ~19 MiB at a 128x512 block); the chip has
+#: 128 MiB of VMEM per core.
+VMEM_LIMIT_BYTES = 64 * 2 ** 20
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
+
+
+def sublanes(dtype) -> int:
+    """Second-minor (sublane) tile of a TPU array: 8 rows of a 32-bit
+    dtype, 16 of a 16-bit one."""
+    return max(8, 32 // jnp.dtype(dtype).itemsize)
+
+
+def tile(dtype, interpret: bool) -> Tuple[int, int]:
+    """``(rows, cols)`` alignment of blocks and windows: the TPU tile
+    when Mosaic compiles the kernel, none in the interpreter.  There,
+    128-lane windows only add work, and they change XLA:CPU's code
+    enough that batched and single-image results differ in the last bit,
+    which the serve contract pins equal."""
+    return (1, 1) if interpret else (sublanes(dtype), LANES)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -129,11 +156,12 @@ def _apply_steps_windows(steps: Sequence[StepSpec], xs: Sequence[jax.Array]
 # ---------------------------------------------------------------------------
 
 def _pick_block_aligned(n: int, target: int, align: int) -> Tuple[int, int]:
-    """Like :func:`_pick_block`, but the block edge must be a multiple of
-    ``align`` (= ``2^levels`` for the fused-pyramid kernel, so every
-    window start is phase-aligned at every pyramid level).  ``n`` itself
-    must already be a multiple of ``align`` (image geometry is validated
-    upstream)."""
+    """``(b, n_padded)`` with the block edge ``b`` a multiple of ``align``
+    near ``target``: an exact divisor of ``n`` when one is at least half
+    the target, else the target with ``n`` padded to a block multiple.
+    ``align`` is the TPU tile for the window kernels (via
+    :func:`_pick_block`) and ``2^levels`` for the fused-pyramid kernel,
+    so every window start is phase-aligned at every pyramid level."""
     t = max(align, (min(n, target) // align) * align)
     d = t
     while d >= align and n % d:
@@ -164,40 +192,52 @@ def _pipeline_ids(grid: Tuple[int, int, int]):
             nb * ni * nj)
 
 
-def _pick_block(n: int, target: int) -> Tuple[int, int]:
+def _pick_block(n: int, target: int, align: int = 1) -> Tuple[int, int]:
     """Block edge and padded plane size for one axis: ``(b, n_padded)``.
 
-    Prefer an exact divisor of ``n`` close to the target (no padding); when
-    only tiny divisors exist (prime / non-smooth plane dims) keep the
-    target-size block and pad the plane up to the next block multiple — the
-    caller slices the output back to ``n``.  This removes the old cliff
-    where e.g. a 509-wide plane degraded to 1-wide blocks.
+    An axis no longer than ``target`` is one block spanning it (a block
+    equal to the whole axis needs no tile alignment).  A longer axis is
+    cut into ``align``-multiple blocks (the TPU tile: :func:`sublanes`
+    rows, :data:`LANES` columns): an exact divisor close to the target
+    when one exists, else the target-size block with the plane padded
+    up to the next block multiple — the caller slices the output back
+    to ``n``.  Non-smooth plane dims therefore never degrade to tiny
+    blocks.
     """
-    b = min(n, target)
-    d = b
-    while n % d:
-        d -= 1
-    if 2 * d >= b:
-        return d, n
-    return b, -(-n // b) * b
+    if n <= target:
+        return n, n
+    return _pick_block_aligned(n, target, align)
 
 
-def _periodic_pad(p: jax.Array, r: int, hp2: int, wp2: int) -> jax.Array:
-    """Extend a plane (..., hp, wp) to (..., hp2 + 2r, wp2 + 2r).
+def _window(block: int, halo: int, align: int) -> int:
+    """Edge of the window DMA'd per block along one axis: the block plus
+    both halos, rounded up to the tile (Mosaic copies whole tiles only,
+    even when one block spans the axis)."""
+    return -(-(block + 2 * halo) // align) * align
+
+
+def _block_ds(idx, n_blocks: int, block: int, size: int, align: int):
+    """Ref slice of block ``idx``'s window: a static whole-axis slice for
+    a single block, else a tile-aligned dynamic start."""
+    if n_blocks == 1:
+        return pl.ds(0, size)
+    return pl.ds(pl.multiple_of(idx * block, align), size)
+
+
+def _periodic_pad(p: jax.Array, lo: int, rows: int, cols: int) -> jax.Array:
+    """Periodic extension of a plane (..., hp, wp) to (..., rows, cols),
+    starting ``lo`` samples before the origin on both axes.
 
     Every output sample holds the periodic (mod hp / mod wp) extension of
-    the *original* plane, so block padding never changes boundary
-    semantics: rows hp..hp2-1 are the wrap-around of rows 0.., not garbage.
+    the *original* plane, so block padding and window rounding never
+    change boundary semantics.
     """
     hp, wp = p.shape[-2:]
-    if r == 0 and (hp2, wp2) == (hp, wp):
+    if (lo, rows, cols) == (0, hp, wp):
         return p
-    if (hp2, wp2) == (hp, wp):
-        cfg = [(0, 0)] * (p.ndim - 2) + [(r, r), (r, r)]
-        return jnp.pad(p, cfg, mode="wrap")
-    ri = jnp.arange(-r, hp2 + r) % hp
-    ci = jnp.arange(-r, wp2 + r) % wp
-    return p[..., ri[:, None], ci[None, :]]
+    cfg = [(0, 0)] * (p.ndim - 2) + [(lo, rows - hp - lo),
+                                     (lo, cols - wp - lo)]
+    return jnp.pad(p, cfg, mode="wrap")
 
 
 def _steps_pallas_call(steps: Tuple[StepSpec, ...], planes, *,
@@ -224,13 +264,15 @@ def _steps_pallas_call(steps: Tuple[StepSpec, ...], planes, *,
     r_total = program.halo if program is not None \
         else sum(st.halo for st in steps)
     nb, hp, wp = planes[0].shape
-    bh, hp2 = _pick_block(hp, block[0])
-    bw, wp2 = _pick_block(wp, block[1])
-    grid = (nb, hp2 // bh, wp2 // bw)
     out_dtype = planes[0].dtype
-
-    padded = [_periodic_pad(p, r_total, hp2, wp2) for p in planes]
-    win = (bh + 2 * r_total, bw + 2 * r_total)
+    th, tw = tile(out_dtype, interpret)
+    bh, hp2 = _pick_block(hp, block[0], th)
+    bw, wp2 = _pick_block(wp, block[1], tw)
+    ni, nj = hp2 // bh, wp2 // bw
+    grid = (nb, ni, nj)
+    win = (_window(bh, r_total, th), _window(bw, r_total, tw))
+    padded = [_periodic_pad(p, r_total, hp2 - bh + win[0],
+                            wp2 - bw + win[1]) for p in planes]
 
     def kernel(*refs):
         x_refs = refs[:4]
@@ -242,8 +284,8 @@ def _steps_pallas_call(steps: Tuple[StepSpec, ...], planes, *,
         def dmas(slot, ids):
             bb, ii, jj = ids
             return [pltpu.make_async_copy(
-                x_refs[k].at[bb, pl.ds(ii * bh, win[0]),
-                             pl.ds(jj * bw, win[1])],
+                x_refs[k].at[bb, _block_ds(ii, ni, bh, win[0], th),
+                             _block_ds(jj, nj, bw, win[1], tw)],
                 scratch[k].at[slot],
                 sems.at[slot, k],
             ) for k in range(4)]
@@ -265,20 +307,22 @@ def _steps_pallas_call(steps: Tuple[StepSpec, ...], planes, *,
             ys = CX.run_window(program, xs, r_total)
         else:
             ys = _apply_steps_windows(steps, xs)
+        # a tile-rounded window computes a few extra rows/columns
         for k in range(4):
-            o_refs[k][0, :, :] = ys[k].astype(out_dtype)
+            o_refs[k][0, :, :] = ys[k][:bh, :bw].astype(out_dtype)
 
     out = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY) for _ in range(4)],
+        in_specs=[pl.BlockSpec(memory_space=pltpu.HBM) for _ in range(4)],
         out_specs=[pl.BlockSpec((1, bh, bw), lambda b, i, j: (b, i, j))
                    for _ in range(4)],
         out_shape=[jax.ShapeDtypeStruct((nb, hp2, wp2), out_dtype)
                    for _ in range(4)],
-        scratch_shapes=[pltpu.VMEM((2,) + win, planes[0].dtype)
+        scratch_shapes=[pltpu.VMEM((2,) + win, out_dtype)
                         for _ in range(4)]
         + [pltpu.SemaphoreType.DMA((2, 4))],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(*padded)
     if (hp2, wp2) != (hp, wp):
@@ -417,7 +461,7 @@ def pyramid_forward_pallas(x, *, levels: int, steps: Tuple[StepSpec, ...],
     M = sched.margins[0]
     win = (bh + 2 * M, bw + 2 * M)
     grid = (nb, hp2 // bh, wp2 // bw)
-    padded = _periodic_pad(x3, M, hp2, wp2)
+    padded = _periodic_pad(x3, M, hp2 + 2 * M, wp2 + 2 * M)
 
     out_levels = pyramid_out_levels(levels)
     out_specs = [pl.BlockSpec((1, bh >> (l + 1), bw >> (l + 1)),
@@ -527,7 +571,8 @@ def pyramid_inverse_pallas(ll, details, *, levels: int,
     padded = []
     for p, l, m in zip(planes, in_levels, in_margins):
         p3 = jnp.asarray(p).reshape((-1,) + p.shape[-2:])
-        padded.append(_periodic_pad(p3, m, hp2 >> (l + 1), wp2 >> (l + 1)))
+        padded.append(_periodic_pad(p3, m, (hp2 >> (l + 1)) + 2 * m,
+                                    (wp2 >> (l + 1)) + 2 * m))
     nb = padded[0].shape[0]
     grid = (nb, hp2 // bh, wp2 // bw)
 

@@ -71,7 +71,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
     mesh = make_production_mesh(multi_pod=multi_pod)
     n_chips = mesh.devices.size
 
-    with SH.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             state_specs, batch = SPEC.input_specs(cfg, run, shape)
             state_sh = _state_shardings(mesh, state_specs, cfg, run)

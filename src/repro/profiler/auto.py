@@ -14,8 +14,8 @@ concrete ``(backend, fuse, block_target, tap_opt)`` by, in order:
    every valid candidate from its analytic features (modeled HBM bytes
    + launches) and nearest measured neighbors;
 3. **cold-start heuristic** — with an empty store, a deterministic
-   platform rule: TPU -> pallas (fuse="pyramid" for multi-level, else
-   "levels"), GPU -> xla/"levels", anything else -> jnp/"levels".
+   platform rule: TPU -> pallas/"levels", GPU -> xla/"levels",
+   anything else -> jnp/"levels".
 
 Every resolution is counted on the telemetry registry
 (:data:`RESOLUTIONS`, labeled by source) and the chosen configs
@@ -106,8 +106,7 @@ def _heuristic(key) -> AutoChoice:
     import jax
     from repro.engine import backends as B
     platform = jax.devices()[0].platform
-    prefs = {"tpu": [("pallas", "pyramid" if key.levels > 1 else "levels"),
-                     ("pallas", "levels")],
+    prefs = {"tpu": [("pallas", "levels")],
              "gpu": [("xla", "levels")]}.get(platform, [])
     prefs += [("jnp", "levels"), ("jnp", "none")]
     for name, fuse in prefs:

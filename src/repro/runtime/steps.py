@@ -182,7 +182,7 @@ def make_train_step_podwise(mesh, cfg: ModelConfig, run: RunConfig):
     """
     compress = run.grad_compression.startswith("dwt")
     levels = _compression_levels(run)
-    from repro.distributed.sharding import _axis_size, shard_map
+    from repro.distributed.sharding import _axis_size
     n_pods = _axis_size(mesh, "pod")
 
     def exchange(grads_p, efb, step_count):
@@ -195,9 +195,9 @@ def make_train_step_podwise(mesh, cfg: ModelConfig, run: RunConfig):
         return jax.tree_util.tree_map(
             lambda a: jax.lax.pmean(a, "pod"), g), efb
 
-    exchange_sm = shard_map(
-        exchange, mesh, in_specs=(P("pod"), P(), P()),
-        out_specs=(P(), P()), manual_axes={"pod"})
+    exchange_sm = jax.shard_map(
+        exchange, mesh=mesh, in_specs=(P("pod"), P(), P()),
+        out_specs=(P(), P()), axis_names={"pod"}, check_vma=False)
 
     def step(state: TrainState, batch):
         # (B, ...) -> (n_pods, B/n_pods, ...): pod becomes a vmapped

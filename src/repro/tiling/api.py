@@ -183,7 +183,6 @@ def _shard_setup(shape, dtype, wavelet, levels, scheme, tiles, optimize,
                  backend, fuse, boundary, compute_dtype, tap_opt, mesh,
                  mesh_axes, inverse: bool):
     from repro import engine as E
-    from repro.distributed import sharding as SH
     if mesh is None:
         raise ValueError("transport='shard_map' requires a mesh (2-D device "
                          "mesh with axes sized like the tile grid)")
@@ -199,13 +198,13 @@ def _shard_setup(shape, dtype, wavelet, levels, scheme, tiles, optimize,
     EX.validate_shard_grid(grid, mesh, mesh_axes, inverse=inverse)
     wshape = grid.inv_window_shape if inverse else grid.window_shape
     wplan = _window_plan(plan.key, wshape)
-    return SH, grid, wplan
+    return grid, wplan
 
 
 def _dwt2_shard_map(x, wavelet, levels, scheme, tiles, optimize, backend,
                     fuse, boundary, compute_dtype, tap_opt, mesh, mesh_axes):
     from jax.sharding import NamedSharding, PartitionSpec as P
-    SH, grid, wplan = _shard_setup(
+    grid, wplan = _shard_setup(
         x.shape, x.dtype, wavelet, levels, scheme, tiles, optimize, backend,
         fuse, boundary, compute_dtype, tap_opt, mesh, mesh_axes, False)
     nrc = grid.grid_shape
@@ -222,7 +221,8 @@ def _dwt2_shard_map(x, wavelet, levels, scheme, tiles, optimize, backend,
         return ll, details
 
     out_specs = (spec, tuple((spec, spec, spec) for _ in range(levels)))
-    f = SH.shard_map(per_shard, mesh, in_specs=spec, out_specs=out_specs)
+    f = jax.shard_map(per_shard, mesh=mesh, in_specs=spec,
+                      out_specs=out_specs, check_vma=False)
     x = jax.device_put(x, NamedSharding(mesh, spec))
     ll, details = f(x)
     return Pyramid(ll=ll, details=list(details))
@@ -234,7 +234,7 @@ def _idwt2_shard_map(pyr, wavelet, levels, scheme, tiles, optimize, backend,
     from jax.sharding import NamedSharding, PartitionSpec as P
     ll = jnp.asarray(pyr.ll)
     shape = (ll.shape[-2] << levels, ll.shape[-1] << levels)
-    SH, grid, wplan = _shard_setup(
+    grid, wplan = _shard_setup(
         shape, ll.dtype, wavelet, levels, scheme, tiles, optimize, backend,
         fuse, boundary, compute_dtype, tap_opt, mesh, mesh_axes, True)
     (th, tw), nrc = grid.tile, grid.grid_shape
@@ -252,7 +252,8 @@ def _idwt2_shard_map(pyr, wavelet, levels, scheme, tiles, optimize, backend,
         return xw[mi:mi + th, mi:mi + tw]
 
     in_specs = (spec, tuple((spec, spec, spec) for _ in range(levels)))
-    f = SH.shard_map(per_shard, mesh, in_specs=in_specs, out_specs=spec)
+    f = jax.shard_map(per_shard, mesh=mesh, in_specs=in_specs,
+                      out_specs=spec, check_vma=False)
     sh = NamedSharding(mesh, spec)
     ll = jax.device_put(ll, sh)
     details = tuple(tuple(jax.device_put(jnp.asarray(d), sh) for d in det)

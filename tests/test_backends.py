@@ -7,6 +7,8 @@ build) and ``backend="xla"`` — compiled tap programs lowered to grouped
 tolerance across every scheme, tap_opt level, pyramid depth, batch
 shape and odd/prime plane size.
 """
+import dataclasses
+
 import numpy as np
 import pytest
 import jax.numpy as jnp
@@ -121,6 +123,36 @@ def test_registry_is_the_dispatch_point():
     import repro.tiling.api
     for mod in (repro.core.transform, repro.tiling.api):
         assert "backend ==" not in open(mod.__file__).read()
+
+
+@pytest.mark.parametrize("platform,interpret", (
+    ("cpu", True), ("tpu", False), ("gpu", False)))
+def test_default_interpret_follows_platform(monkeypatch, platform,
+                                            interpret):
+    """The interpreter is the CPU path only; a TPU compiles the kernels
+    with Mosaic, and any other platform has no Pallas path at all."""
+    from repro.kernels import polyphase as PP
+    monkeypatch.setattr(B.jax, "default_backend", lambda: platform)
+    assert PP._default_interpret() is interpret
+    key = E.PlanKey(WAVELET, "ns-polyconv", 2, (32, 32), "float32",
+                    "pallas", False, "levels", "periodic")
+    if platform == "gpu":
+        with pytest.raises(B.BackendError, match="platform 'gpu'"):
+            B.get_backend("pallas").validate(key)
+    else:
+        B.get_backend("pallas").validate(key)
+
+
+def test_pyramid_refused_on_tpu_and_auto_avoids_it(monkeypatch):
+    from repro.profiler import auto as PA
+    key = E.PlanKey(WAVELET, "ns-polyconv", 3, (64, 64), "float32",
+                    "pallas", False, "pyramid", "periodic")
+    B.get_backend("pallas").validate(key)            # CPU: interpreter
+    monkeypatch.setattr(B.jax, "default_backend", lambda: "tpu")
+    with pytest.raises(B.BackendError, match="PlanKey.fuse='pyramid'"):
+        B.get_backend("pallas").validate(key)
+    auto = dataclasses.replace(key, backend="auto")
+    assert ("pallas", "pyramid", "full") not in PA.enumerate_candidates(auto)
 
 
 # ---------------------------------------------------------------------------

@@ -109,7 +109,7 @@ def test_window_and_roll_executors_agree():
     planes = [_rand((12, 14), seed=4 + k) for k in range(4)]
     rolled = X.run_planes(prog, planes)
     # windows = periodic pad of the planes
-    xs = [PP._periodic_pad(p, r, *p.shape) for p in planes]
+    xs = [PP._periodic_pad(p, r, 12 + 2 * r, 14 + 2 * r) for p in planes]
     windowed = X.run_window(prog, xs, r)
     for a, b in zip(rolled, windowed):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -188,6 +188,19 @@ def test_bf16_compute_dtype_parity(backend):
         scale = np.abs(a).max()
         assert np.abs(a - b).max() <= 0.15 * scale
         assert np.abs(a - b).mean() <= 0.03 * scale
+
+
+def test_xla_conv_requests_full_float32_precision():
+    """The TPU's default conv precision rounds float32 to bfloat16; the
+    xla backend's convs must ask for HIGHEST to keep cross-backend parity."""
+    import jax
+    f = jax.jit(lambda v: T.dwt2(v, wavelet="cdf97", levels=2,
+                                 scheme="ns-polyconv", backend="xla"))
+    convs = [ln for ln in f.lower(_rand((32, 64), seed=6)).as_text()
+             .splitlines() if "stablehlo.convolution" in ln]
+    assert convs
+    assert all("precision HIGHEST>, #stablehlo<precision HIGHEST" in ln
+               for ln in convs)
 
 
 def test_compute_dtype_is_part_of_plan_key():

@@ -44,7 +44,7 @@ def test_sharded_train_step_end_to_end():
         shape = ShapeConfig('s', 'train', 32, 8)
         batch = {k: jnp.asarray(v) for k, v in
                  make_pipeline(cfg).batch_at(0, shape).items()}
-        with SH.use_mesh(mesh):
+        with jax.set_mesh(mesh):
             state = steps.init_train_state(jax.random.PRNGKey(0), cfg, run)
             sspec = jax.eval_shape(lambda: state)
             shd = SH.make_param_shardings(mesh, sspec.params, cfg, run)
@@ -79,7 +79,7 @@ def test_podwise_compressed_step_reduces_and_runs():
         shape = ShapeConfig('s', 'train', 32, 8)
         batch = {k: jnp.asarray(v) for k, v in
                  make_pipeline(cfg).batch_at(0, shape).items()}
-        with SH.use_mesh(mesh):
+        with jax.set_mesh(mesh):
             state = steps.init_train_state(jax.random.PRNGKey(0), cfg, run)
             step = steps.make_train_step_podwise(mesh, cfg, run)
             jstep = jax.jit(step)

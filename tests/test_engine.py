@@ -178,6 +178,30 @@ def test_nonsmooth_plane_dims_use_wide_blocks():
                                    rtol=2e-4, atol=2e-5)
 
 
+@pytest.mark.parametrize("fuse", ("none", "levels"))
+def test_tpu_tiled_windows_match_reference(monkeypatch, fuse):
+    """The TPU block/window rule, run in the interpreter: blocks on the
+    (8, 128) tile, windows rounded up to whole tiles, planes padded
+    periodically to cover them.  Numerics must not notice."""
+    from repro.kernels import polyphase as PP
+    monkeypatch.setattr(PP, "tile", lambda dtype, interpret:
+                        (PP.sublanes(dtype), PP.LANES))
+    x = _rand((2, 76, 532), seed=8)       # 38x266 planes, both non-smooth
+    key = E.PlanKey("cdf97", "ns-polyconv", 2, x.shape, "float32",
+                    "pallas", False, fuse, "periodic")
+    plan = E.build_plan(key, block_target=(16, 128))
+    assert plan.level_specs[0].block == (16, 128)
+    assert plan.level_specs[0].padded_shape == (48, 384)
+    pyr = plan.execute(x)
+    ref = T.dwt2(x, wavelet="cdf97", levels=2, scheme="ns-polyconv")
+    for a, b in zip([pyr.ll] + [d for det in pyr.details for d in det],
+                    [ref.ll] + [d for det in ref.details for d in det]):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(plan.execute_inverse(pyr)),
+                               np.asarray(x), rtol=1e-3, atol=1e-4)
+
+
 # ---------------------------------------------------------------------------
 # LRU eviction + stats() accuracy under a mixed key population
 # ---------------------------------------------------------------------------

@@ -100,6 +100,43 @@ def test_inverse_dispatch_recovers_too():
     assert np.array_equal(np.asarray(out), ref)
 
 
+def test_compile_failure_propagates_without_retry_or_degradation(
+        monkeypatch):
+    """A plan whose own kernel fails to lower or compile is a defect: it
+    raises, and no other backend serves it in its place.  A runtime
+    fault on the same plan still walks the degradation chain."""
+    from repro import engine
+    from repro.engine import executor as X
+    from repro.faults.policy import RETRIES
+
+    class Refused(RuntimeError):
+        pass
+
+    def refuse(*args, **kw):
+        raise Refused("kernel refused by the compiler")
+
+    x = _img((48, 40), seed=7)
+    cache = engine.PlanCache()
+    plan = engine.get_plan(shape=x.shape, levels=2, backend="pallas",
+                           fuse="levels", cache=cache)
+    monkeypatch.setattr(X, "pallas_level_forward", refuse)
+    with pytest.raises(DG.KernelCompileError) as ei:
+        plan.execute(x)
+    assert isinstance(ei.value.__cause__, Refused)
+    assert sum(s["value"] for s in FALLBACKS.series()) == 0
+    assert sum(s["value"] for s in RETRIES.series()) == 0
+    monkeypatch.undo()
+
+    runtime = engine.get_plan(shape=x.shape, levels=2, backend="pallas",
+                              fuse="levels", cache=cache)
+    ref = np.asarray(dwt2(x, levels=2, backend="jnp").ll)
+    _arm("execute.forward=always")
+    out = runtime.execute(x)
+    FJ.activate(None)
+    assert np.allclose(np.asarray(out.ll), ref, rtol=1e-3, atol=1e-4)
+    assert sum(s["value"] for s in FALLBACKS.series()) == 1
+
+
 def test_engine_stats_faults_section_live():
     from repro import engine
     _arm("execute.forward=once")

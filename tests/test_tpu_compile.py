@@ -1,0 +1,87 @@
+"""Compiles of the main-path kernels for a described TPU v5e.
+
+The TPU compiler ships with jaxlib's TPU plug-in, so a plan's executors
+can be lowered and compiled for a v5e that is described, not attached.
+That catches what the Pallas interpreter cannot: ref slices not aligned
+to the (8, 128) tile, kernels over their scoped VMEM, programs that do
+not fit the device.  Nothing runs, so results and times are not checked.
+
+The topology is described inside a module fixture (never at import),
+so only the test worker given this file loads the TPU library.  The
+plan code asks ``jax.default_backend()`` for its platform, which here
+is the CPU: the tests steer it to the TPU path themselves.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro import engine as E
+from repro.engine import backends as B
+from repro.engine import plan as PL
+from repro.kernels import polyphase as PP
+
+DCI4K = (3, 2160, 4096)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """Build and trace plans as on a TPU: Mosaic-compiled kernels with
+    tile-aligned blocks, no interpreter."""
+    monkeypatch.setattr(PP, "_default_interpret", lambda: False)
+
+
+def _key(scheme, backend, fuse, levels=1, wavelet="cdf97"):
+    return PL.PlanKey(wavelet, scheme, levels, DCI4K, "float32", backend,
+                      False, fuse, "periodic")
+
+
+def _compile(plan, one_chip, inverse):
+    x = jax.ShapeDtypeStruct(plan.key.shape, jnp.float32)
+    if not inverse:
+        return jax.jit(plan._forward).lower(jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip)).compile()
+    pyr = jax.eval_shape(plan._forward, x)
+    pyr = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        pyr)
+    return jax.jit(plan._inverse).lower(*pyr).compile()
+
+
+@pytest.mark.parametrize("inverse", (False, True), ids=("fwd", "inv"))
+@pytest.mark.parametrize("scheme", ("ns-polyconv", "sep-lifting"))
+def test_window_kernel_compiles_for_v5e(scheme, inverse, one_chip, mosaic):
+    plan = PL.build_plan(_key(scheme, "pallas", "none"))
+    bh, bw = plan.level_specs[0].block
+    assert bh % PP.sublanes(jnp.float32) == 0 and bw % PP.LANES == 0
+    text = _compile(plan, one_chip, inverse).as_text()
+    assert "tpu_custom_call" in text          # the Pallas kernel is there
+
+
+def test_pyramid_rejected_at_plan_build_on_tpu(monkeypatch, mosaic):
+    monkeypatch.setattr(B.jax, "default_backend", lambda: "tpu")
+    with pytest.raises(E.BackendError, match="PlanKey.fuse='pyramid'"):
+        PL.build_plan(_key("ns-polyconv", "pallas", "pyramid", levels=4))
+
+
+@pytest.mark.parametrize("inverse", (False, True), ids=("fwd", "inv"))
+def test_xla_levels_compiles_for_v5e(inverse, one_chip, mosaic):
+    plan = PL.build_plan(_key("ns-polyconv", "xla", "levels"))
+    text = _compile(plan, one_chip, inverse).as_text()
+    # float32 operands reach the MXU unrounded (bf16 passes lose parity)
+    assert "operand_precision={highest,highest}" in text
