@@ -8,7 +8,7 @@ Sections:
                 the paper's OpenCL column, plus the tap-program
                 compiler's lowered/compiled MAC counts.
   2. fig789   — paper Figures 7/8/9 (throughput vs image size per scheme):
-                CPU-measured + v5e HBM-model projections.
+                CPU-measured.
   3. engine   — plan/executor engine: batched images/sec, plan-cached vs
                 seed-style per-call dispatch (both backends).
   4. kernels  — per-kernel roofline (steps -> HBM round trips on TPU)

@@ -1,17 +1,11 @@
 """Benchmark: paper Figures 7/8/9 — transform performance per scheme,
 plus the plan/executor engine's batched-throughput comparison.
 
-The paper measures GB/s versus image size on two GPUs.  This container is
-CPU-only, so the analogue has two parts:
-
-1. **measured** — wall-clock GB/s of the jitted pure-JAX scheme
-   implementations on CPU (relative scheme ordering under a real
-   memory hierarchy);
-2. **TPU model** — projected GB/s on a v5e from the kernel HBM-traffic
-   model (one pallas_call per step; DESIGN.md §2): the paper's step
-   halving appears directly as a throughput doubling for the memory-
-   bound transform, and the beyond-paper fused variant collapses every
-   scheme to one HBM round trip.
+The paper measures GB/s versus image size on two GPUs.  The analogue
+here is wall-clock GB/s of the jitted pure-JAX scheme implementations
+on the CPU (relative scheme ordering under a real memory hierarchy).
+Device numbers come from the chip benchmark (``bench/``), not from
+here.
 
 ``engine_throughput`` measures the production question instead: batched
 images/sec through the plan-cached engine (one cached plan, one traced
@@ -28,9 +22,6 @@ import jax.numpy as jnp
 from repro import engine as E
 from repro.core import schemes as S
 from repro.core import transform as T
-from repro.kernels import ops as K
-
-HBM_BW = 819e9  # v5e
 
 
 def measure_cpu(wname: str, scheme: str, n: int, reps: int = 3) -> float:
@@ -48,12 +39,6 @@ def measure_cpu(wname: str, scheme: str, n: int, reps: int = 3) -> float:
         jax.block_until_ready(f(x))
     dt = (time.perf_counter() - t0) / reps
     return x.nbytes / dt / 1e9
-
-
-def tpu_model(wname: str, scheme: str, n: int, fuse: str = "none") -> float:
-    st = K.scheme_stats(wname, scheme, optimize=True, shape=(n, n),
-                        itemsize=4, fuse=fuse)
-    return (n * n * 4) / (st["hbm_bytes"] / HBM_BW) / 1e9
 
 
 def _seed_style_dwt2(x, wavelet: str, scheme: str, levels: int):
@@ -260,31 +245,16 @@ def dwt3_throughput(n: int = 64, t_frames: int = 8, levels: int = 2,
 
 def main(sizes=(512, 1024, 2048), wavelets=("cdf53", "cdf97", "dd137")):
     print("# Figures 7/8/9 analogue: GB/s per scheme vs image size")
-    print("wavelet,scheme,size,cpu_measured_GBps,tpu_model_GBps,"
-          "tpu_model_fused_GBps,steps")
-    results = {}
+    print("wavelet,scheme,size,cpu_measured_GBps,steps")
     rows = []
     for wname in wavelets:
         for sc in S.SCHEMES:
             steps = S.build_scheme(wname, sc).num_steps
             for n in sizes:
                 cpu = measure_cpu(wname, sc, n)
-                tpu = tpu_model(wname, sc, n)
-                tpuf = tpu_model(wname, sc, n, fuse="scheme")
-                results[(wname, sc, n)] = (cpu, tpu)
                 rows.append({"wavelet": wname, "scheme": sc, "size": n,
-                             "cpu_gbps": cpu, "tpu_model_gbps": tpu,
-                             "tpu_model_fused_gbps": tpuf, "steps": steps})
-                print(f"{wname},{sc},{n},{cpu:.2f},{tpu:.1f},{tpuf:.1f},"
-                      f"{steps}")
-    # the paper's headline check at the largest size
-    n = sizes[-1]
-    for wname in wavelets:
-        ns_conv = results[(wname, "ns-conv", n)]
-        sep_conv = results[(wname, "sep-conv", n)]
-        print(f"# {wname}: ns-conv/sep-conv TPU-model speedup = "
-              f"{ns_conv[1] / sep_conv[1]:.2f}x "
-              f"(paper: non-separable wins for CDF wavelets)")
+                             "cpu_gbps": cpu, "steps": steps})
+                print(f"{wname},{sc},{n},{cpu:.2f},{steps}")
     return rows
 
 

@@ -288,22 +288,26 @@ Planes = Tuple[jax.Array, jax.Array, jax.Array, jax.Array]
 
 
 def to_planes(x: jax.Array) -> Planes:
-    """Split an image (..., H, W) into the four polyphase planes."""
-    return (
-        x[..., 0::2, 0::2],
-        x[..., 0::2, 1::2],
-        x[..., 1::2, 0::2],
-        x[..., 1::2, 1::2],
-    )
+    """Split an image (..., H, W) into the four polyphase planes (under
+    the ``dwt.to_planes`` scope, which names its device ops)."""
+    with jax.named_scope("dwt.to_planes"):
+        return (
+            x[..., 0::2, 0::2],
+            x[..., 0::2, 1::2],
+            x[..., 1::2, 0::2],
+            x[..., 1::2, 1::2],
+        )
 
 
 def from_planes(planes: Planes) -> jax.Array:
-    """Interleave four (..., H/2, W/2) planes back into (..., H, W)."""
-    x1, x2, x3, x4 = planes
-    top = jnp.stack([x1, x2], axis=-1).reshape(*x1.shape[:-1], -1)
-    bot = jnp.stack([x3, x4], axis=-1).reshape(*x3.shape[:-1], -1)
-    out = jnp.stack([top, bot], axis=-2)
-    return out.reshape(*top.shape[:-2], -1, top.shape[-1])
+    """Interleave four (..., H/2, W/2) planes back into (..., H, W)
+    (under the ``dwt.from_planes`` scope)."""
+    with jax.named_scope("dwt.from_planes"):
+        x1, x2, x3, x4 = planes
+        top = jnp.stack([x1, x2], axis=-1).reshape(*x1.shape[:-1], -1)
+        bot = jnp.stack([x3, x4], axis=-1).reshape(*x3.shape[:-1], -1)
+        out = jnp.stack([top, bot], axis=-2)
+        return out.reshape(*top.shape[:-2], -1, top.shape[-1])
 
 
 def apply_poly(p: P.Poly, x: jax.Array) -> jax.Array:

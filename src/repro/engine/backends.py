@@ -168,31 +168,35 @@ class Backend:
         raise NotImplementedError
 
     def make_forward(self, plan):
-        """Build the forward executor: x -> (ll, details coarsest-first)."""
+        """Build the forward executor: x -> (ll, details coarsest-first).
+        Jitted, it is the executable ``dwt_forward``; each level's ops
+        carry the scope ``dwt.level<i>``."""
         key, specs = plan.key, plan.level_specs
 
-        def run(x):
+        def dwt_forward(x):
             details = []
             ll = x
             for spec in specs:
                 # spans no-op while jax traces (fuse="levels"/"pyramid");
                 # eager chains get one timed span per level
                 with T.span("level.forward", level=spec.index,
-                            backend=self.name):
+                            backend=self.name), \
+                        jax.named_scope(f"dwt.level{spec.index}"):
                     ll, hl, lh, hh = self.level_forward(ll, spec, key)
                 details.append((hl, lh, hh))
             return ll, tuple(details[::-1])
 
         if key.fuse == "pyramid":
-            return self._pyramid_forward(plan, run)
+            return self._pyramid_forward(plan, dwt_forward)
         if key.fuse == "levels":
             # one trace for the whole pyramid: levels chain without
             # returning to Python between them
-            return X.compiled_jit(run)
+            return X.compiled_jit(dwt_forward)
         if self.jit_per_level:
             # seed-granularity dispatch (one jitted call per level), but
             # with plan-resolved steps/blocks instead of per-call rebuilds
-            fns = [self._jit_level(self.level_forward, spec, key)
+            fns = [self._jit_level(self.level_forward, spec, key,
+                                   "dwt_forward")
                    for spec in specs]
 
             def run_jit(x):
@@ -206,25 +210,28 @@ class Backend:
                 return ll, tuple(details[::-1])
 
             return run_jit
-        return run
+        return dwt_forward
 
     def make_inverse(self, plan):
-        """Build the inverse executor: (ll, details coarsest-first) -> x."""
+        """Build the inverse executor: (ll, details coarsest-first) -> x;
+        jitted, the executable ``dwt_inverse``."""
         key, specs = plan.key, plan.level_specs
 
-        def run(ll, details):
+        def dwt_inverse(ll, details):
             for spec, (hl, lh, hh) in zip(reversed(specs), details):
                 with T.span("level.inverse", level=spec.index,
-                            backend=self.name):
+                            backend=self.name), \
+                        jax.named_scope(f"dwt.level{spec.index}"):
                     ll = self.level_inverse((ll, hl, lh, hh), spec, key)
             return ll
 
         if key.fuse == "pyramid":
-            return self._pyramid_inverse(plan, run)
+            return self._pyramid_inverse(plan, dwt_inverse)
         if key.fuse == "levels":
-            return X.compiled_jit(run)
+            return X.compiled_jit(dwt_inverse)
         if self.jit_per_level:
-            fns = [self._jit_level(self.level_inverse, spec, key)
+            fns = [self._jit_level(self.level_inverse, spec, key,
+                                   "dwt_inverse")
                    for spec in specs]
 
             def run_jit(ll, details):
@@ -236,11 +243,17 @@ class Backend:
                 return ll
 
             return run_jit
-        return run
+        return dwt_inverse
 
     @staticmethod
-    def _jit_level(level_fn, spec, key):
-        return X.compiled_jit(lambda v: level_fn(v, spec, key))
+    def _jit_level(level_fn, spec, key, name: str):
+        """One level as its own executable, ``<name>_l<i>``."""
+        def level(v):
+            with jax.named_scope(f"dwt.level{spec.index}"):
+                return level_fn(v, spec, key)
+
+        level.__name__ = f"{name}_l{spec.index}"
+        return X.compiled_jit(level)
 
     def _pyramid_forward(self, plan, run):
         """fuse="pyramid" policy for backends without a megakernel:

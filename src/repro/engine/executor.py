@@ -19,7 +19,7 @@ into the leading grid dimension of the ``pallas_call``.
 """
 from __future__ import annotations
 
-import functools
+import time
 from typing import Sequence
 
 import jax
@@ -34,6 +34,16 @@ from repro.faults import degrade as DG
 from repro.faults import inject as FI
 
 
+COMPILES = T.counter(
+    "repro_executor_compiles_total",
+    "plan-executor compiles (lower + compile, persistent-cache loads "
+    "included), by executable", labelnames=("op",))
+COMPILE_SECONDS = T.counter(
+    "repro_executor_compile_seconds_total",
+    "host seconds in plan-executor lower + compile, by executable",
+    labelnames=("op",))
+
+
 def compiled_jit(fn):
     """``jax.jit(fn)`` whose lowering and compilation are checked apart
     from its execution.
@@ -45,9 +55,33 @@ def compiled_jit(fn):
     plan on another backend.  The call itself then reuses that
     executable (JAX shares the compilation between ``lower().compile()``
     and the call).  Called under an outer trace, it traces inline.
+
+    The executable is named after ``fn`` (``jit_dwt_forward``).  Each
+    compile is a ``plan.compile`` span and counts in
+    ``repro_executor_compiles_total`` / ``_compile_seconds_total``
+    (label ``op``: ``fn``'s name); under ``spans`` it also records the
+    executable's op-to-scope map (:func:`repro.telemetry.op_scopes`).
+    Later calls do none of this.
     """
+    name = fn.__name__
     jitted = jax.jit(fn)
     checked = set()
+
+    def compile_once(args):
+        t0 = time.perf_counter()
+        with T.span("plan.compile", op=name):
+            try:
+                compiled = jitted.lower(*args).compile()
+            except FI.InjectedFault:
+                raise
+            except Exception as e:
+                raise DG.KernelCompileError(
+                    f"plan executor failed to lower or compile: "
+                    f"{type(e).__name__}: {e}") from e
+        COMPILES.inc(op=name)
+        COMPILE_SECONDS.inc(time.perf_counter() - t0, op=name)
+        if T.CONFIG.spans_on:
+            T.record_op_scopes(compiled.as_text())
 
     def call(*args):
         leaves, tree = jax.tree_util.tree_flatten(args)
@@ -56,14 +90,7 @@ def compiled_jit(fn):
                                 getattr(a, "sharding", None))
                                for a in leaves))
             if sig not in checked:
-                try:
-                    jitted.lower(*args).compile()
-                except FI.InjectedFault:
-                    raise
-                except Exception as e:
-                    raise DG.KernelCompileError(
-                        f"plan executor failed to lower or compile: "
-                        f"{type(e).__name__}: {e}") from e
+                compile_once(args)
                 checked.add(sig)
         return jitted(*args)
 
@@ -133,7 +160,8 @@ def pallas_level_forward(x, spec, key):
         spec.fwd_steps, planes,
         fuse=("none" if key.fuse == "none" else "scheme"),
         block=spec.block, compute_dtype=jnp.dtype(key.compute_dtype),
-        tap_opt=key.tap_opt, programs=spec.fwd_programs)
+        tap_opt=key.tap_opt, programs=spec.fwd_programs,
+        level=spec.index)
 
 
 def pallas_level_inverse(planes, spec, key):
@@ -141,7 +169,8 @@ def pallas_level_inverse(planes, spec, key):
         spec.inv_steps, planes,
         fuse=("none" if key.fuse == "none" else "scheme"),
         block=spec.block, compute_dtype=jnp.dtype(key.compute_dtype),
-        tap_opt=key.tap_opt, programs=spec.inv_programs)
+        tap_opt=key.tap_opt, programs=spec.inv_programs,
+        level=spec.index, inverse=True)
     return S.from_planes(planes)
 
 
@@ -194,7 +223,7 @@ def make_packet_forward(plan, backend):
     tree = PK.PacketTree(key.packet)
     internal, leaves = tree.internal_nodes(), tree.leaves
 
-    def run(x):
+    def packet_forward(x):
         nodes = {"": x}
         for path in internal:
             spec = specs[len(path)]
@@ -205,7 +234,7 @@ def make_packet_forward(plan, backend):
                 nodes[path + c] = arr
         return tuple(nodes[p] for p in leaves)
 
-    return _fuse_trace(plan, backend, run)
+    return _fuse_trace(plan, backend, packet_forward)
 
 
 def make_packet_inverse(plan, backend):
@@ -217,7 +246,7 @@ def make_packet_inverse(plan, backend):
     tree = PK.PacketTree(key.packet)
     internal, leaves = tree.internal_nodes(), tree.leaves
 
-    def run(leaf_arrays):
+    def packet_inverse(leaf_arrays):
         nodes = dict(zip(leaves, leaf_arrays))
         for path in reversed(internal):
             spec = specs[len(path)]
@@ -227,7 +256,7 @@ def make_packet_inverse(plan, backend):
                 nodes[path] = backend.level_inverse(children, spec, key)
         return nodes[""]
 
-    return _fuse_trace(plan, backend, run)
+    return _fuse_trace(plan, backend, packet_inverse)
 
 
 def make_dwt3_forward(plan, backend):
@@ -241,7 +270,7 @@ def make_dwt3_forward(plan, backend):
     prog = TP.compile_temporal(key.wavelet)
     cdt = jnp.dtype(key.compute_dtype)
 
-    def run(x):
+    def dwt3_forward(x):
         details = []
         v = x
         for spec in specs:
@@ -253,7 +282,7 @@ def make_dwt3_forward(plan, backend):
             details.append((hl0, lh0, hh0, llh, hlh, lhh, hhh))
         return v, tuple(details[::-1])
 
-    return _fuse_trace(plan, backend, run)
+    return _fuse_trace(plan, backend, dwt3_forward)
 
 
 def make_dwt3_inverse(plan, backend):
@@ -263,7 +292,7 @@ def make_dwt3_inverse(plan, backend):
     prog = TP.compile_temporal(key.wavelet, inverse=True)
     cdt = jnp.dtype(key.compute_dtype)
 
-    def run(ll, details):
+    def dwt3_inverse(ll, details):
         v = ll
         for spec, det in zip(reversed(specs), details):
             hl0, lh0, hh0, llh, hlh, lhh, hhh = det
@@ -274,7 +303,7 @@ def make_dwt3_inverse(plan, backend):
                 v = TP.temporal_inverse(lo, hi, prog, cdt)
         return v
 
-    return _fuse_trace(plan, backend, run)
+    return _fuse_trace(plan, backend, dwt3_inverse)
 
 
 # ---------------------------------------------------------------------------
@@ -302,8 +331,12 @@ def make_pyramid_forward(plan):
     from repro.engine import plan as PLAN
     levels = plan.key.levels
     scheme = plan.key.scheme
-    fn = compiled_jit(functools.partial(
-        PP.pyramid_forward_pallas, **_pyramid_kernel_kwargs(plan, False)))
+    kw = _pyramid_kernel_kwargs(plan, False)
+
+    def dwt_forward(x):
+        return PP.pyramid_forward_pallas(x, **kw)
+
+    fn = compiled_jit(dwt_forward)
 
     def run(x):
         PLAN.PYRAMID_LAUNCHES.inc()
@@ -321,8 +354,12 @@ def make_pyramid_inverse(plan):
     from repro.engine import plan as PLAN
     levels = plan.key.levels
     scheme = plan.key.scheme
-    fn = compiled_jit(functools.partial(
-        PP.pyramid_inverse_pallas, **_pyramid_kernel_kwargs(plan, True)))
+    kw = _pyramid_kernel_kwargs(plan, True)
+
+    def dwt_inverse(ll, details):
+        return PP.pyramid_inverse_pallas(ll, details, **kw)
+
+    fn = compiled_jit(dwt_inverse)
 
     def run(ll, details):
         PLAN.PYRAMID_LAUNCHES.inc()

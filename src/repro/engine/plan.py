@@ -277,12 +277,10 @@ class DwtPlan:
         EXECUTIONS.inc(op="forward", backend=k.backend, fuse=k.fuse,
                        scheme=k.scheme)
         with T.span("execute.forward", backend=k.backend, fuse=k.fuse,
-                    scheme=k.scheme, levels=k.levels) as sp:
+                    scheme=k.scheme, levels=k.levels):
             # resilient dispatch: retry in place, then walk the
             # capability-checked degradation chain (repro.faults.degrade)
             out = R.dispatch(self, "forward", (x,))
-        if sp.duration is not None:
-            T.record_execution(self, sp.duration, op="forward")
         if k.packet is not None:
             return WaveletPacket2D(paths=k.packet, leaves=list(out))
         ll, details = out
@@ -311,11 +309,8 @@ class DwtPlan:
         EXECUTIONS.inc(op="inverse", backend=k.backend, fuse=k.fuse,
                        scheme=k.scheme)
         with T.span("execute.inverse", backend=k.backend, fuse=k.fuse,
-                    scheme=k.scheme, levels=k.levels) as sp:
-            out = R.dispatch(self, "inverse", args)
-        if sp.duration is not None:
-            T.record_execution(self, sp.duration, op="inverse")
-        return out
+                    scheme=k.scheme, levels=k.levels):
+            return R.dispatch(self, "inverse", args)
 
 
 def _resolve_level(index: int, h: int, w: int, key: PlanKey,

@@ -61,7 +61,7 @@ def apply_scheme_pallas(x, *, wavelet: str = "cdf97",
         out = PP.apply_steps_pallas(steps, tuple(x), fuse=kfuse,
                                     block=block, interpret=interpret,
                                     compute_dtype=cdt, tap_opt=tap_opt,
-                                    programs=programs)
+                                    programs=programs, inverse=True)
         return S.from_planes(out)
     steps = _scheme_steps(wavelet, scheme, optimize, False)
     planes = S.to_planes(x)

@@ -230,21 +230,31 @@ def _periodic_pad(p: jax.Array, lo: int, rows: int, cols: int) -> jax.Array:
 
     Every output sample holds the periodic (mod hp / mod wp) extension of
     the *original* plane, so block padding and window rounding never
-    change boundary semantics.
+    change boundary semantics.  Its device ops carry the ``dwt.pad``
+    scope.
     """
     hp, wp = p.shape[-2:]
     if (lo, rows, cols) == (0, hp, wp):
         return p
     cfg = [(0, 0)] * (p.ndim - 2) + [(lo, rows - hp - lo),
                                      (lo, cols - wp - lo)]
-    return jnp.pad(p, cfg, mode="wrap")
+    with jax.named_scope("dwt.pad"):
+        return jnp.pad(p, cfg, mode="wrap")
+
+
+def kernel_name(inverse: bool, level: int, step: int) -> str:
+    """The stable name of one window kernel (``pallas_call``), which
+    the device trace shows: ``dwt_fwd_l<level>_s<step>`` or
+    ``dwt_inv_l<level>_s<step>``."""
+    return f"dwt_{'inv' if inverse else 'fwd'}_l{level}_s{step}"
 
 
 def _steps_pallas_call(steps: Tuple[StepSpec, ...], planes, *,
                        block: Tuple[int, int], interpret: Optional[bool],
-                       compute_dtype=jnp.float32,
+                       name: str, compute_dtype=jnp.float32,
                        program: Optional[C.TapProgram] = None):
-    """One pallas_call executing ``steps`` (fused) over the four planes.
+    """One pallas_call, named ``name``, executing ``steps`` (fused) over
+    the four planes.
 
     ``planes`` are batched ``(B, hp, wp)``; the batch is the leading grid
     dimension, so one call covers the whole batch with no vmap round trip.
@@ -324,9 +334,11 @@ def _steps_pallas_call(steps: Tuple[StepSpec, ...], planes, *,
         + [pltpu.SemaphoreType.DMA((2, 4))],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
+        name=name,
     )(*padded)
     if (hp2, wp2) != (hp, wp):
-        out = [o[:, :hp, :wp] for o in out]
+        with jax.named_scope("dwt.crop"):
+            out = [o[:, :hp, :wp] for o in out]
     return tuple(out)
 
 
@@ -336,7 +348,8 @@ def apply_steps_pallas(steps: Sequence[StepSpec], planes, *,
                        interpret: Optional[bool] = None,
                        compute_dtype=jnp.float32,
                        tap_opt: str = "full",
-                       programs: Optional[Tuple[C.TapProgram, ...]] = None):
+                       programs: Optional[Tuple[C.TapProgram, ...]] = None,
+                       level: int = 0, inverse: bool = False):
     """Execute a scheme's steps on the four polyphase planes.
 
     ``planes`` may carry arbitrary leading batch dims ``(..., hp, wp)``;
@@ -352,7 +365,8 @@ def apply_steps_pallas(steps: Sequence[StepSpec], planes, *,
     reference; "exact" compiles without reassociation; "full" applies all
     passes).  Pre-compiled ``programs`` (one per pallas_call under the
     chosen fuse mode, e.g. from a :class:`repro.engine.plan.DwtPlan`)
-    skip recompilation.
+    skip recompilation.  ``level`` and ``inverse`` only name the kernels
+    (:func:`kernel_name`).
     """
     steps = tuple(steps)
     if fuse not in ("none", "scheme"):
@@ -369,12 +383,14 @@ def apply_steps_pallas(steps: Sequence[StepSpec], planes, *,
     if fuse == "scheme":
         p3 = _steps_pallas_call(steps, p3, block=block,
                                 interpret=interpret,
+                                name=kernel_name(inverse, level, 0),
                                 compute_dtype=compute_dtype,
                                 program=programs[0] if programs else None)
     else:
         for i, st in enumerate(steps):
             p3 = _steps_pallas_call((st,), p3, block=block,
                                     interpret=interpret,
+                                    name=kernel_name(inverse, level, i),
                                     compute_dtype=compute_dtype,
                                     program=programs[i] if programs
                                     else None)
@@ -522,15 +538,17 @@ def pyramid_forward_pallas(x, *, levels: int, steps: Tuple[StepSpec, ...],
         scratch_shapes=[pltpu.VMEM((2,) + win, out_dtype),
                         pltpu.SemaphoreType.DMA((2,))],
         interpret=interpret,
+        name="dwt_pyramid_fwd",
     )(padded)
 
     def clip(o, l):
         o = o[:, :h >> (l + 1), :w >> (l + 1)]
         return o.reshape(batch + o.shape[-2:])
 
-    ll = clip(outs[0], levels - 1)
-    details = tuple(tuple(clip(outs[1 + 3 * l + d], l) for d in range(3))
-                    for l in range(levels))
+    with jax.named_scope("dwt.crop"):
+        ll = clip(outs[0], levels - 1)
+        details = tuple(tuple(clip(outs[1 + 3 * l + d], l)
+                              for d in range(3)) for l in range(levels))
     return ll, details
 
 
@@ -624,8 +642,10 @@ def pyramid_inverse_pallas(ll, details, *, levels: int,
         scratch_shapes=[pltpu.VMEM((2,) + wn, out_dtype) for wn in wins]
         + [pltpu.SemaphoreType.DMA((2, n_in))],
         interpret=interpret,
+        name="dwt_pyramid_inv",
     )(*padded)
-    return out[:, :h, :w].reshape(batch + (h, w))
+    with jax.named_scope("dwt.crop"):
+        return out[:, :h, :w].reshape(batch + (h, w))
 
 
 def pyramid_vmem_bytes(levels: int, win_shapes: Sequence[Tuple[int, int]],

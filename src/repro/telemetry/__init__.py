@@ -14,9 +14,13 @@ serve-only latency tracker (see docs/observability.md):
   parents in a bounded ring, exported as Perfetto-loadable
   Chrome-trace JSON (:func:`repro.telemetry.export.chrome_trace`),
   optionally mirrored into ``jax.profiler.TraceAnnotation``;
-* **attribution** (:mod:`repro.telemetry.attribution`) — measured span
-  / profiler time joined with the analytic HBM-byte and MAC models
-  into achieved-GB/s / achieved-MACs/s gauges (a live roofline).
+* **attribution** (:mod:`repro.telemetry.attribution`) — profiled
+  device time joined with the analytic HBM-byte and MAC models into
+  achieved-GB/s / achieved-MACs/s gauges (a live roofline);
+* **op scopes** (:mod:`repro.telemetry.scopes`) — under ``spans``, each
+  executor compile maps its HLO instructions to the program's
+  ``jax.named_scope`` layers (``dwt.to_planes``, ``dwt.pad``, ...), so
+  a device trace's ops can be put on those layers (:func:`op_scopes`).
 
 Everything is gated on ``$REPRO_TELEMETRY`` (``off`` | ``counters``
 [default] | ``spans``): under ``off`` every instrument site is a
@@ -39,6 +43,8 @@ from repro.telemetry.export import (chrome_trace, parse_prometheus_text,
 from repro.telemetry.registry import (DEFAULT_BUCKETS, MAX_SERIES, REGISTRY,
                                       Counter, CounterAlias, Gauge,
                                       Histogram, MetricsRegistry)
+from repro.telemetry.scopes import op_scopes, record_op_scopes
+from repro.telemetry import scopes as _scopes
 from repro.telemetry.spans import (NOOP_SPAN, TRACER, SpanRecord, SpanTracer,
                                    current_span, span, span_summary)
 
@@ -58,6 +64,8 @@ __all__ = [
     "write_chrome_trace",
     # attribution
     "record_execution", "plan_cost_inputs", "plan_macs", "roofline",
+    # op scopes
+    "op_scopes", "record_op_scopes",
 ]
 
 
@@ -84,10 +92,11 @@ def snapshot() -> dict:
 
 
 def reset() -> None:
-    """Zero every metric series and clear the span ring (per-test
-    isolation; metric definitions survive)."""
+    """Zero every metric series, clear the span ring and the op-scope
+    map (per-test isolation; metric definitions survive)."""
     REGISTRY.reset()
     TRACER.clear()
+    _scopes.clear()
 
 
 def stats() -> dict:

@@ -18,13 +18,11 @@ all labeled ``(scheme, backend, fuse, levels, op)`` — a live roofline
 per plan, the measured-vs-modeled comparison the profiler's CostModel
 previously did blind.
 
-Two callers feed it: :func:`repro.profiler.trace.profile_plan` (honest
-device time — ``block_until_ready`` around the median of reps) and the
-``execute.*`` spans under ``REPRO_TELEMETRY=spans`` (span wall-clock;
-on async backends that is dispatch + any synchronous work, a lower
-bound on device time — see docs/observability.md).  Attribution inputs
-are computed once per plan and cached on the plan object, so the
-per-execution cost is two divisions and two gauge writes.
+One caller feeds it: :func:`repro.profiler.trace.profile_plan`, which
+times with ``block_until_ready`` around the median of reps.  The
+``execute.*`` spans do not: on an async backend they time the enqueue,
+not the device.  Attribution inputs are computed once per plan and
+cached on the plan object.
 """
 from __future__ import annotations
 

@@ -296,6 +296,123 @@ def test_attribution_handles_tap_opt_off_and_bad_measurements():
     assert T.record_execution(plan, -1.0) is None
 
 
+def test_execute_spans_no_longer_feed_the_roofline_gauges():
+    """An ``execute.*`` span times the enqueue; only profile_plan's
+    blocked timing publishes achieved GB/s."""
+    T.set_mode("spans")
+    dwt2(np.zeros((16, 16), np.float32), levels=1, backend="jnp",
+         fuse="levels")
+    assert "execute.forward" in {r.name for r in T.TRACER.records()}
+    assert T.roofline() == []
+
+
+# -- op scopes and executor compiles -----------------------------------
+
+def _compile_pair(shape=(3, 32, 64), levels=2, backend="pallas"):
+    """Forward and inverse of a fresh plan (no shared plan cache, so
+    its executors compile here)."""
+    plan = engine.get_plan(shape=shape, levels=levels, backend=backend,
+                           fuse="levels", cache=engine.PlanCache())
+    x = np.random.default_rng(0).standard_normal(shape).astype(np.float32)
+    out = plan.execute_inverse(plan.execute(x))
+    np.testing.assert_allclose(np.asarray(out), x, atol=1e-4)
+    return plan
+
+
+def test_op_scopes_name_the_layers_of_a_compiled_plan():
+    T.set_mode("spans")
+    _compile_pair()
+    scopes = T.op_scopes()
+    assert {"jit_dwt_forward", "jit_dwt_inverse"} <= set(scopes)
+    fwd = set(scopes["jit_dwt_forward"].values())
+    inv = set(scopes["jit_dwt_inverse"].values())
+    assert {"dwt.to_planes", "dwt.pad", "dwt.level0", "dwt.level1"} <= fwd
+    assert {"dwt.from_planes", "dwt.pad"} <= inv
+    assert "dwt.from_planes" not in fwd and "dwt.to_planes" not in inv
+    names = [r.name for r in T.TRACER.records()]
+    assert names.count("plan.compile") == 2
+    assert T.snapshot()["repro_executor_compiles_total"]["series"] == [
+        {"labels": {"op": "dwt_forward"}, "value": 1},
+        {"labels": {"op": "dwt_inverse"}, "value": 1}]
+
+
+def test_counters_mode_keeps_no_scope_map_but_counts_compiles():
+    from repro.engine import executor as X
+    _compile_pair(shape=(2, 32, 32), levels=1, backend="jnp")
+    assert T.op_scopes() == {}
+    assert T.TRACER.records() == []
+    assert X.COMPILES.value(op="dwt_forward") == 1
+    assert X.COMPILES.value(op="dwt_inverse") == 1
+    assert X.COMPILE_SECONDS.value(op="dwt_forward") > 0
+
+
+def test_compile_counters_count_compiles_not_calls():
+    from repro.engine import executor as X
+    plan = _compile_pair(shape=(2, 32, 32), levels=1, backend="jnp")
+    x = np.ones((2, 32, 32), np.float32)
+    for _ in range(3):
+        plan.execute(x)
+    assert X.COMPILES.value(op="dwt_forward") == 1
+
+
+def test_kernels_and_per_level_executables_have_stable_names():
+    from repro.engine import executor as X
+    from repro.kernels import polyphase as PP
+    assert PP.kernel_name(False, 2, 0) == "dwt_fwd_l2_s0"
+    assert PP.kernel_name(True, 0, 3) == "dwt_inv_l0_s3"
+    T.set_mode("spans")
+    plan = engine.get_plan(shape=(32, 32), levels=2, backend="pallas",
+                           fuse="none", cache=engine.PlanCache())
+    plan.execute(np.ones((32, 32), np.float32))
+    assert {"jit_dwt_forward_l0", "jit_dwt_forward_l1"} <= set(
+        T.op_scopes())
+    assert X.COMPILES.value(op="dwt_forward_l1") == 1
+
+
+HLO = """HloModule jit_dwt_forward, is_scheduled=true
+
+%fused_computation (param_0: f32[4,8]) -> f32[2,8] {
+  %param_0 = f32[4,8]{1,0} parameter(0)
+  %gather.1 = f32[2,8]{1,0} gather(f32[4,8]{1,0} %param_0), metadata={op_name="jit(dwt_forward)/dwt.level0/dwt.to_planes/gather"}
+  %slice.2 = f32[2,8]{1,0} slice(%gather.1), metadata={op_name="jit(dwt_forward)/dwt.level0/dwt.to_planes/slice"}
+  ROOT %concatenate.3 = f32[2,8]{1,0} concatenate(%slice.2), metadata={op_name="jit(dwt_forward)/dwt.level0/dwt.pad/concatenate"}
+}
+
+ENTRY %main.9 (x.1: f32[4,8]) -> f32[2,8] {
+  %x.1 = f32[4,8]{1,0} parameter(0), metadata={op_name="x"}
+  %copy.4 = f32[4,8]{0,1:T(8,128)} copy(%x.1), metadata={op_name="x"}
+  %fusion.5 = f32[2,8]{1,0} fusion(%copy.4), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(dwt_forward)/dwt.level0/dwt.pad/concatenate"}
+  %copy.6 = f32[2,8]{0,1} copy(f32[2,8]{1,0:T(8,128)} %fusion.5)
+  %dwt_fwd_l0_s0.1 = f32[2,8]{1,0} custom-call(%copy.6), custom_call_target="tpu_custom_call", metadata={op_name="jit(dwt_forward)/dwt.level0/dwt_fwd_l0_s0"}
+  ROOT %bitcast.7 = f32[2,8]{1,0} bitcast(%dwt_fwd_l0_s0.1)
+}
+"""
+
+
+def test_scope_map_rules_on_hlo_text():
+    from repro.telemetry import scopes as SC
+    assert SC.scope_of("jit(f)/dwt.level1/dwt.pad/jit(_pad)/slice") == \
+        "dwt.pad"
+    assert SC.scope_of("x") is None
+    module, m = SC.parse(HLO)
+    assert module == "jit_dwt_forward"
+    assert m == {
+        # the fusion: two of its three instructions are dwt.to_planes
+        "fusion.5": "dwt.to_planes",
+        # a copy of the parameter takes its user's scope (and so does
+        # the parameter, which runs no device op)
+        "copy.4": "dwt.to_planes",
+        "x.1": "dwt.to_planes",
+        # a layout copy and a bitcast take their producer's
+        "copy.6": "dwt.to_planes",
+        "dwt_fwd_l0_s0.1": "dwt.level0",
+        "bitcast.7": "dwt.level0"}
+    assert T.record_op_scopes(HLO) == "jit_dwt_forward"
+    assert T.op_scopes()["jit_dwt_forward"] == m
+    T.reset()
+    assert T.op_scopes() == {}
+
+
 # -- engine.stats() schema contract -----------------------------------
 
 def test_engine_stats_schema_exact_top_level_keys():

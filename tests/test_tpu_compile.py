@@ -71,6 +71,31 @@ def test_window_kernel_compiles_for_v5e(scheme, inverse, one_chip, mosaic):
     assert bh % PP.sublanes(jnp.float32) == 0 and bw % PP.LANES == 0
     text = _compile(plan, one_chip, inverse).as_text()
     assert "tpu_custom_call" in text          # the Pallas kernel is there
+    # under its stable name, which the device trace shows
+    assert f"%{PP.kernel_name(inverse, 0, 0)}" in text
+
+
+@pytest.mark.parametrize("inverse", (False, True), ids=("fwd", "inv"))
+def test_every_device_op_of_the_levels_path_has_a_scope(inverse, one_chip,
+                                                       mosaic):
+    """The benchmark's path (pallas, fuse="levels"): each instruction
+    of the compiled entry computation, the ops the trace shows, maps to
+    a ``dwt.*`` scope, and the kernels carry their names."""
+    from repro.telemetry import scopes as SC
+    plan = PL.build_plan(_key("ns-polyconv", "pallas", "levels", levels=2))
+    text = _compile(plan, one_chip, inverse).as_text()
+    _, scopes = SC.parse(text)
+    idle = ("parameter(", "constant(", " tuple(", "get-tuple-element(")
+    run = [ln.split(" = ")[0].split()[-1].lstrip("%")
+           for ln in text[text.index("\nENTRY"):].splitlines()[1:]
+           if " = " in ln and not any(k in ln for k in idle)]
+    assert run and [o for o in run if o not in scopes] == []
+    layer = "dwt.from_planes" if inverse else "dwt.to_planes"
+    assert {layer, "dwt.pad", "dwt.level0", "dwt.level1"} <= set(
+        scopes.values())
+    for level in (0, 1):
+        assert scopes[f"{PP.kernel_name(inverse, level, 0)}.1"] == \
+            f"dwt.level{level}"
 
 
 def test_pyramid_rejected_at_plan_build_on_tpu(monkeypatch, mosaic):
