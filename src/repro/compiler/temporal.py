@@ -23,6 +23,7 @@ import functools
 from typing import Tuple
 
 import jax.numpy as jnp
+from jax import lax
 
 from repro.core import wavelets as W
 
@@ -90,7 +91,9 @@ def temporal_split(x):
         raise ValueError(
             f"temporal axis must be even, got T={x.shape[TIME_AXIS]} "
             f"in shape {tuple(x.shape)}")
-    return x[..., 0::2, :, :], x[..., 1::2, :, :]
+    # static strided slices: a strided index lowers to a ``gather``
+    return (lax.slice_in_dim(x, 0, None, 2, axis=TIME_AXIS),
+            lax.slice_in_dim(x, 1, None, 2, axis=TIME_AXIS))
 
 
 def temporal_merge(s, d):

@@ -36,6 +36,7 @@ from typing import Dict, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.core import poly as P
 from repro.core.wavelets import Wavelet, get_wavelet
@@ -288,15 +289,18 @@ Planes = Tuple[jax.Array, jax.Array, jax.Array, jax.Array]
 
 
 def to_planes(x: jax.Array) -> Planes:
-    """Split an image (..., H, W) into the four polyphase planes (under
-    the ``dwt.to_planes`` scope, which names its device ops)."""
+    """Split an image (..., H, W) into the four polyphase planes
+    ``x[..., i::2, j::2]`` (under the ``dwt.to_planes`` scope, which
+    names its device ops).
+
+    Static strided slices, never a strided index: ``jnp`` lowers that to
+    a ``gather`` of one element at a time.  The Pallas forward splits
+    with a kernel instead (:func:`repro.kernels.polyphase.to_planes`)."""
+    lead = (0,) * (jnp.ndim(x) - 2)
+    strides = (1,) * len(lead) + (2, 2)
     with jax.named_scope("dwt.to_planes"):
-        return (
-            x[..., 0::2, 0::2],
-            x[..., 0::2, 1::2],
-            x[..., 1::2, 0::2],
-            x[..., 1::2, 1::2],
-        )
+        return tuple(lax.slice(x, lead + (i, j), jnp.shape(x), strides)
+                     for i in (0, 1) for j in (0, 1))
 
 
 def from_planes(planes: Planes) -> jax.Array:

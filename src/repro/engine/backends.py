@@ -416,6 +416,9 @@ class PallasBackend(Backend):
         return X.compiled_jit(run)
 
     def launches(self, plan) -> int:
+        """Step kernels per execution; the forward's polyphase split
+        kernel (one a level where ``polyphase.split_fits``) is not
+        counted."""
         if plan.key.fuse == "none":
             return plan.num_steps
         if plan.key.fuse == "pyramid" and plan.pyramid is not None:

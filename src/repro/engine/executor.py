@@ -155,7 +155,7 @@ def jnp_level_inverse(planes, spec, key):
 # ---------------------------------------------------------------------------
 
 def pallas_level_forward(x, spec, key):
-    planes = S.to_planes(x)
+    planes = PP.to_planes(x)
     return PP.apply_steps_pallas(
         spec.fwd_steps, planes,
         fuse=("none" if key.fuse == "none" else "scheme"),

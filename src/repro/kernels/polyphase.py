@@ -398,6 +398,83 @@ def apply_steps_pallas(steps: Sequence[StepSpec], planes, *,
 
 
 # ---------------------------------------------------------------------------
+# The polyphase split of the Pallas forward
+# ---------------------------------------------------------------------------
+
+#: name of the split kernel (``pallas_call``) in the device trace
+SPLIT_KERNEL = "dwt_split"
+
+
+def split_fits(x) -> bool:
+    """The shapes :func:`split_planes` takes: a 32-bit ``(..., H, W)``
+    with even H and W a multiple of 256 (each plane's rows a whole
+    number of 128-lane tiles)."""
+    h, w = jnp.shape(x)[-2:]
+    return (jnp.dtype(x.dtype).itemsize == 4 and h % 2 == 0
+            and w % (2 * LANES) == 0)
+
+
+def _split_kernel(x_ref, o00, o01, o10, o11, t_ref, *, chunks):
+    """One (2L, chunks * 2L) block of the image into four
+    (L, chunks * L) blocks of the planes, L = 128.  Mosaic loads a
+    sublane stride only from a 128-lane buffer and no lane stride at
+    all, so each 128-lane column strip goes through ``t_ref``: rows
+    split by a strided load; columns by a transpose, a strided load and
+    a transpose back."""
+    n = LANES
+    outs = ((o00, o01), (o10, o11))
+    for k in range(chunks):
+        rows = ([], [])               # rows[i][c]: rows i::2 of strip c
+        for c in range(2):
+            t_ref[...] = x_ref[0, :, pl.ds((2 * k + c) * n, n)]
+            for i in range(2):
+                rows[i].append(t_ref[pl.ds(i, n, stride=2), :])
+        for i in range(2):
+            t_ref[pl.ds(0, n), :] = rows[i][0].T
+            t_ref[pl.ds(n, n), :] = rows[i][1].T   # columns on sublanes
+            for j in range(2):
+                outs[i][j][0, :, pl.ds(k * n, n)] = \
+                    t_ref[pl.ds(j, n, stride=2), :].T
+
+
+def to_planes(x: jax.Array):
+    """The polyphase split of a Pallas forward level: the split kernel
+    where :func:`split_fits`, else :func:`repro.core.schemes.to_planes`
+    (strided slices); both under the ``dwt.to_planes`` scope."""
+    if not split_fits(x):
+        return S.to_planes(x)
+    with jax.named_scope("dwt.to_planes"):
+        return split_planes(x)
+
+
+def split_planes(x: jax.Array):
+    """``x[..., i::2, j::2]`` for (i, j) in (0,0), (0,1), (1,0), (1,1),
+    bit for bit, as one Pallas kernel: one HBM read and one write of
+    the image (``split_fits(x)`` must hold).  A strided index or slice
+    of the lane axis is a gather or a per-element copy on the TPU."""
+    *lead, h, w = x.shape
+    xb = x.reshape(-1, h, w)
+    chunks = math.gcd(w // (2 * LANES), 4)
+    bw = 2 * LANES * chunks
+    plane = jax.ShapeDtypeStruct((xb.shape[0], h // 2, w // 2), x.dtype)
+    out_spec = pl.BlockSpec((1, LANES, bw // 2), lambda b, i, j: (b, i, j))
+    planes = pl.pallas_call(
+        functools.partial(_split_kernel, chunks=chunks),
+        grid=(xb.shape[0], pl.cdiv(h // 2, LANES), w // bw),
+        in_specs=[pl.BlockSpec((1, 2 * LANES, bw),
+                               lambda b, i, j: (b, i, j))],
+        out_specs=[out_spec] * 4,
+        out_shape=[plane] * 4,
+        scratch_shapes=[pltpu.VMEM((2 * LANES, LANES), x.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * 3),
+        interpret=_default_interpret(),
+        name=SPLIT_KERNEL,
+    )(xb)
+    return tuple(p.reshape(*lead, h // 2, w // 2) for p in planes)
+
+
+# ---------------------------------------------------------------------------
 # Fused-pyramid megakernel: the whole multi-level transform in one call
 # ---------------------------------------------------------------------------
 
@@ -692,9 +769,9 @@ def scheme_hbm_bytes(steps: Sequence[StepSpec], shape: Tuple[int, int],
     ``split_merge`` counts the polyphase deinterleave (``to_planes``,
     forward) / reinterleave (``from_planes``, inverse) that every
     non-pyramid plan actually pays per transform: one extra read + write
-    of the full image, as a separate XLA gather/scatter pass outside the
-    kernels.  The fused-pyramid kernel splits/merges in-VMEM and is
-    modelled by :func:`pyramid_hbm_bytes`, which omits it.
+    of the full image, as a separate pass outside the level kernels.
+    The fused-pyramid kernel splits/merges in-VMEM and is modelled by
+    :func:`pyramid_hbm_bytes`, which omits it.
 
     ``programs`` (one compiled tap program per call group) narrows the
     halo to the compiled per-axis margin when available.

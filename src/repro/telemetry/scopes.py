@@ -6,7 +6,7 @@ A profiler trace of the TPU names each device op by its HLO instruction
 names its layers with ``jax.named_scope`` (``dwt.level0``,
 ``dwt.to_planes``, ``dwt.pad``, ...), which reach the compiled HLO as
 each instruction's ``metadata={op_name="jit(dwt_forward)/dwt.level0/
-dwt.to_planes/gather"}``.  :func:`record_op_scopes` reads that text once
+dwt.to_planes/slice"}``.  :func:`record_op_scopes` reads that text once
 per compile and keeps ``{module: {instruction: scope}}``, so a reader of
 the trace can put each device op's time on a program layer.
 

@@ -7,9 +7,11 @@
   (Table 1, OpenCL column) exactly for 13 of its 14 cells.
 """
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.compiler import temporal as T
 from repro.core import optimize as O
 from repro.core import poly as P
 from repro.core import schemes as S
@@ -119,3 +121,80 @@ def test_polyconv_equals_conv_for_single_pair():
         b = S.build_scheme(wname, "ns-polyconv")
         assert a.num_steps == b.num_steps == 1
         assert P.mat_max_diff(a.total_matrix(), b.total_matrix()) < 1e-9
+
+
+def _bits(a):
+    a = np.asarray(a)
+    return a.shape, a.dtype, a.tobytes()
+
+
+def _seeded(shape, dtype, seed=0):
+    x = np.random.default_rng(seed).normal(size=shape) * 1000
+    return jnp.asarray(x).astype(dtype)
+
+
+@pytest.mark.parametrize("hw", [(8, 8), (6, 10), (7, 10), (8, 9)],
+                         ids=lambda s: "x".join(map(str, s)))
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16", "int32"])
+@pytest.mark.parametrize("lead", [(), (3,), (2, 3)], ids=("2d", "c", "bc"))
+def test_to_planes_is_the_strided_index_bit_for_bit(lead, dtype, hw):
+    """``to_planes`` (strided slices) returns exactly ``x[..., i::2,
+    j::2]``: shapes, dtypes and bits, eager and jitted, odd H or W
+    included; on even H and W ``from_planes`` undoes it."""
+    x = _seeded(lead + hw, dtype)
+    want = [np.asarray(x)[..., i::2, j::2] for i in (0, 1) for j in (0, 1)]
+    for split in (S.to_planes, jax.jit(S.to_planes)):
+        got = split(x)
+        assert [_bits(g) for g in got] == [_bits(w) for w in want]
+    if hw[0] % 2 == 0 and hw[1] % 2 == 0:
+        assert _bits(S.from_planes(S.to_planes(x))) == _bits(x)
+
+
+@pytest.mark.parametrize("hw", [(8, 256), (36, 512), (270, 768)],
+                         ids=lambda s: "x".join(map(str, s)))
+@pytest.mark.parametrize("dtype", ["float32", "int32"])
+@pytest.mark.parametrize("lead", [(), (3,), (2, 3)], ids=("2d", "c", "bc"))
+def test_split_kernel_is_the_strided_index_bit_for_bit(lead, dtype, hw):
+    """The TPU's split kernel (run by the Pallas interpreter here)
+    returns exactly ``x[..., i::2, j::2]``, NaN, -0 and inf included,
+    over partial row blocks and 1, 2 or 3 column strips."""
+    from repro.kernels import polyphase as PP
+    x = _seeded(lead + hw, dtype)
+    if dtype == "float32":
+        x = x.at[..., 1, 3].set(jnp.nan).at[..., 2, 5].set(-0.0) \
+            .at[..., 5, 130].set(-jnp.inf)
+    assert PP.split_fits(x)
+    want = [np.asarray(x)[..., i::2, j::2] for i in (0, 1) for j in (0, 1)]
+    got = PP.split_planes(x)
+    assert [_bits(g) for g in got] == [_bits(w) for w in want]
+
+
+@pytest.mark.parametrize("shape,dtype,kernel", [
+    ((3, 36, 512), "float32", True),
+    ((36, 256), "int32", True),
+    ((3, 36, 384), "float32", False),     # W not a multiple of 256
+    ((3, 35, 512), "float32", False),     # odd H
+    ((3, 36, 512), "bfloat16", False),    # 16-bit
+])
+def test_pallas_split_takes_the_kernel_where_it_fits(shape, dtype, kernel):
+    """The Pallas forward's split runs the split kernel for the shapes
+    it fits and strided slices for the rest; both give the strided
+    index.  ``core.schemes.to_planes`` never launches a kernel."""
+    from repro.kernels import polyphase as PP
+    x = _seeded(shape, dtype)
+    assert ("pallas_call" in str(jax.make_jaxpr(PP.to_planes)(x))) == kernel
+    assert "pallas_call" not in str(jax.make_jaxpr(S.to_planes)(x))
+    want = [np.asarray(x)[..., i::2, j::2] for i in (0, 1) for j in (0, 1)]
+    assert [_bits(g) for g in PP.to_planes(x)] == [_bits(w) for w in want]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16", "int32"])
+@pytest.mark.parametrize("lead", [(), (3,), (2, 3)], ids=("3d", "c", "bc"))
+def test_temporal_split_is_the_strided_index_bit_for_bit(lead, dtype):
+    """``temporal_split`` (strided slices) returns exactly ``x[..., i::2,
+    :, :]`` on the time axis, and ``temporal_merge`` undoes it."""
+    x = _seeded(lead + (6, 4, 5), dtype)
+    s, d = T.temporal_split(x)
+    assert [_bits(s), _bits(d)] == [
+        _bits(np.asarray(x)[..., i::2, :, :]) for i in (0, 1)]
+    assert _bits(T.temporal_merge(s, d)) == _bits(x)

@@ -321,7 +321,7 @@ def _compile_pair(shape=(3, 32, 64), levels=2, backend="pallas"):
 
 def test_op_scopes_name_the_layers_of_a_compiled_plan():
     T.set_mode("spans")
-    _compile_pair()
+    _compile_pair(shape=(3, 32, 512))      # wide enough for the split kernel
     scopes = T.op_scopes()
     assert {"jit_dwt_forward", "jit_dwt_inverse"} <= set(scopes)
     fwd = set(scopes["jit_dwt_forward"].values())
