@@ -31,13 +31,16 @@ def pyramid_leaves(pyr) -> list:
     return [pyr.ll] + [band for det in pyr.details for band in det]
 
 
-def describe_plan(ctx, shape, dtype: str = "float32") -> str:
-    """The plan ``backend="auto"`` resolved to for ``shape``: what the
-    run logs, so a changed choice shows."""
+def describe_plan(ctx, shape) -> str:
+    """The plan ``backend="auto"`` resolved to for ``shape`` in the
+    coefficients' dtype: what the run logs, so a changed choice shows.
+    The samples' ``bit_depth`` is an argument of the transform, not of
+    the plan."""
     from repro import engine
     c = ctx.config
     kw = ctx.transform_kwargs()
-    plan = engine.get_plan(shape=tuple(shape), dtype=dtype,
+    kw.pop("bit_depth", None)
+    plan = engine.get_plan(shape=tuple(shape), dtype=c["dtype"],
                            levels=c["levels"], **kw)
     k = plan.key
     src = plan.auto.source if getattr(plan, "auto", None) else "asked"

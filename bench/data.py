@@ -3,8 +3,11 @@
 The seed is any whole number, often wider than 32 bits; it is
 hashed into one threefry key, so every seed gives its own data and the
 same seed the same data.  Samples are ``bit_depth``-bit integers,
-DC-level-shifted as JPEG 2000 does before its transform, held as
-float32: uniform noise, so every subband carries full-range data.
+uniform noise, so every subband carries full-range data.  A
+configuration that states ``samples`` (an integer dtype such as
+``"uint16"``) gets them as the product stores them: unsigned, in that
+container.  Without it they are DC-level-shifted as JPEG 2000 does
+before its transform and held in the configuration's ``dtype``.
 """
 from __future__ import annotations
 
@@ -22,16 +25,34 @@ def host_rng(seed: int) -> np.random.Generator:
     return np.random.default_rng(np.random.SeedSequence(int(seed)))
 
 
-def images(seed: int, n: int, shape, bit_depth: int) -> tuple:
-    """``n`` images of ``shape``, made in one jitted call."""
+def samples_dtype(config: dict) -> np.dtype:
+    """The dtype a configuration's samples are held in: ``samples``
+    where it states one, else its ``dtype``."""
+    return np.dtype(config.get("samples", config["dtype"]))
+
+
+def integer_samples(config: dict) -> bool:
+    return np.issubdtype(samples_dtype(config), np.integer)
+
+
+def images(seed: int, n: int, shape, bit_depth: int,
+           dtype="float32") -> tuple:
+    """``n`` images of ``shape`` in ``dtype``, made in one jitted call:
+    unsigned samples in ``[0, 2**bit_depth)`` for an integer ``dtype``,
+    DC-level-shifted ones for a float ``dtype``; the same draws either
+    way."""
     import jax
-    import jax.numpy as jnp
+    dtype = np.dtype(dtype)
+    integer = np.issubdtype(dtype, np.integer)
+    if integer and (1 << bit_depth) - 1 > np.iinfo(dtype).max:
+        raise ValueError(f"{bit_depth}-bit samples do not fit {dtype}")
     half = 1 << (bit_depth - 1)
+    shift = 0 if integer else half
 
     def make(k):
         ks = jax.random.split(k, n)
         return tuple((jax.random.randint(ki, tuple(shape), 0, 2 * half)
-                      - half).astype(jnp.float32) for ki in ks)
+                      - shift).astype(dtype) for ki in ks)
 
     return jax.jit(make)(key(seed))
 

@@ -9,7 +9,7 @@ import math
 import numpy as np
 
 from bench import closed_loop, compare, data, reference
-from bench.harness import Check, Window
+from bench.harness import BenchError, Check, Window
 
 SPAN = "bench.decode"
 
@@ -19,6 +19,9 @@ def setup(ctx):
     from repro.core import idwt2
     from repro.engine.pyramid import Pyramid
     c = ctx.config
+    if data.integer_samples(c):
+        raise BenchError(f"the decode driver takes float samples; "
+                         f"{c['name']!r} states {c['samples']!r} samples")
     lv = c["levels"]
     ring = [Pyramid(ll=p[0], details=[tuple(p[1 + 3 * i:4 + 3 * i])
                                       for i in range(lv)])
@@ -45,7 +48,9 @@ def run(ctx, st):
     ctx.log(f"[loop] {done} inverses of {mpix:.3f} Mpix in {secs:.3f} s")
     return Window(seconds=secs, attempted=done, failed=0,
                   metrics={"decode_mpix_s": done * mpix / secs},
-                  extra={"dispatch_s": dispatch, "transforms": done})
+                  extra={"dispatch_s": dispatch, "transforms": done,
+                         "io_dtypes": (ctx.config["dtype"],
+                                       data.samples_dtype(ctx.config))})
 
 
 def check(ctx, st):

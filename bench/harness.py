@@ -10,6 +10,13 @@ import time
 from pathlib import Path
 from typing import Callable, Optional
 
+from bench import data
+
+
+class BenchError(RuntimeError):
+    """A cell that cannot run here: unknown names, no TPU, too few
+    chips, a configuration its traffic cannot take."""
+
 
 def log(*parts) -> None:
     print(*parts, file=sys.stderr, flush=True)
@@ -119,12 +126,16 @@ class Ctx:
 
     def transform_kwargs(self) -> dict:
         """The engine arguments the configuration states; the control
-        (``--control``) computes in bfloat16 instead of float32."""
+        (``--control``) computes in bfloat16 instead of float32.
+        Integer samples carry their precision (``bit_depth``) beside
+        them, as JPEG 2000's SIZ marker does."""
         c = self.config
-        return dict(wavelet=c["wavelet"], scheme=c["scheme"],
-                    backend=c["backend"], fuse=c["fuse"],
-                    compute_dtype="bfloat16" if self.control
-                    else c["dtype"])
+        kw = dict(wavelet=c["wavelet"], scheme=c["scheme"],
+                  backend=c["backend"], fuse=c["fuse"],
+                  compute_dtype="bfloat16" if self.control else c["dtype"])
+        if data.integer_samples(c):
+            kw["bit_depth"] = c["bit_depth"]
+        return kw
 
     def annotate(self, name: str):
         """A host span in the profiler's trace (no-op untraced)."""

@@ -49,12 +49,8 @@ sys.path.insert(0, str(ROOT / "src"))
 sys.path.insert(0, str(ROOT))
 
 from bench import ideal_bytes  # noqa: E402
-from bench.harness import Check, CompileStats, Ctx, log  # noqa: E402
-
-
-class BenchError(RuntimeError):
-    """A cell that cannot run here: unknown names, no TPU, too few
-    chips."""
+from bench.harness import (BenchError, Check, CompileStats, Ctx,  # noqa: E402
+                           log)
 
 
 # -- discovery by name ------------------------------------------------------

@@ -1,8 +1,9 @@
 """Drive a cell of the chip benchmark at a tiny size on the CPU.
 
 The harness's look for a TPU is skipped and the configuration is cut
-to a few kilopixels; everything else (data from the seed, warm-up,
-window, output check) is the benchmark's own.
+to the few kilopixels its file states under ``cpu`` (a shape and a
+level count); everything else (data from the seed, warm-up, window,
+output check) is the benchmark's own.
 
     python tests/bench/bench_cpu_run.py <cell> [--control] [--fault F]
 """
@@ -21,25 +22,26 @@ sys.path.insert(0, str(ROOT))
 from bench import run as R  # noqa: E402
 from bench.harness import GcPauses  # noqa: E402
 
-#: tiny shapes per configuration (the published ones are chip-sized)
-SMALL = {"dci4k-j2k97": dict(shape=[3, 32, 64], levels=2)}
-
-
 def small_config(name: str) -> dict:
+    """Configuration ``name`` at the size its ``cpu`` key states (the
+    published sizes are chip-sized)."""
     cfg = R._json("configs", name)
-    cfg.update(SMALL[name])
+    if "cpu" not in cfg:
+        raise R.BenchError(f"configuration {name!r} states no 'cpu' key "
+                           f"(the shape and levels of a CPU run)")
+    cfg.update(cfg["cpu"])
     return cfg
 
 
-def _perturb_pyramid(pyr):
-    """One coefficient moved by one 12-bit step."""
-    pyr.ll = pyr.ll.at[(0,) * pyr.ll.ndim].add(1.0)
+def _perturb_pyramid(pyr, step: float):
+    pyr.ll = pyr.ll.at[(0,) * pyr.ll.ndim].add(step)
     return pyr
 
 
 @contextlib.contextmanager
-def armed(fault: str):
-    """Break the timed path underneath the harness while inside."""
+def armed(fault: str, step: float):
+    """Break the timed path underneath the harness while inside:
+    ``"answer"`` moves one output value of every call by ``step``."""
     from repro.engine import plan as PL
     saved = []
 
@@ -50,10 +52,10 @@ def armed(fault: str):
     if fault == "answer":
         fwd, inv = PL.DwtPlan.execute, PL.DwtPlan.execute_inverse
         patch(PL.DwtPlan, "execute",
-              lambda self, x: _perturb_pyramid(fwd(self, x)))
+              lambda self, x: _perturb_pyramid(fwd(self, x), step))
         patch(PL.DwtPlan, "execute_inverse",
               lambda self, p: inv(self, p).at[
-                  (0,) * len(self.key.shape)].add(1.0))
+                  (0,) * len(self.key.shape)].add(step))
     elif fault:
         raise ValueError(f"unknown fault {fault!r}")
     try:
@@ -68,9 +70,13 @@ def run_cell(name: str, monkeypatch, *, seconds: float = 0.5,
              fault: str = "") -> dict:
     """One run in this process; returns the result line's object.
     ``monkeypatch`` (pytest's) undoes the stand-ins: the CPU devices for
-    the TPU, the small configuration for the published one."""
+    the TPU, the small configuration for the published one.  The
+    ``"answer"`` fault moves one value by 1/4096 of the sample range
+    (one step of a 12-bit sample), the same share at every bit depth."""
     import jax
     from repro import compile_cache
+    config = R.find_cell(name)["config"]
+    step = 2.0 ** (small_config(config)["bit_depth"] - 12)
     argv = ["--workload", name, "--seed", str(seed), "--seconds",
             str(seconds), "--trace", "0"] + (["--control"] if control
                                              else [])
@@ -83,7 +89,7 @@ def run_cell(name: str, monkeypatch, *, seconds: float = 0.5,
                 "REPRO_PROFILE_STORE"):
         monkeypatch.delenv(env, raising=False)   # restored afterwards
     try:
-        with armed(fault):
+        with armed(fault, step):
             return R.run(R.parse_args(argv))
     finally:
         gc.callbacks[:] = [cb for cb in gc.callbacks

@@ -1,6 +1,7 @@
 """Everything a cell needs is found by name, and BENCHMARK.json keeps to
 the shape the harness reads."""
 import json
+import math
 import re
 
 import pytest
@@ -75,3 +76,23 @@ def test_config_files_hold_the_run_shapes(cfg):
     assert h % (1 << c["levels"]) == 0 and w % (1 << c["levels"]) == 0
     assert set(cfg["reduced"]) == set(c["reduced"])
     assert c["limits"]["rel_err"] > 0
+
+
+def test_no_harness_code_names_a_configuration():
+    """A configuration is data: the harness's code and the CPU driver of
+    the tests never name one, so a later configuration needs no edit."""
+    files = sorted(R.BENCH.rglob("*.py")) + [
+        R.ROOT / "tests" / "bench" / "bench_cpu_run.py"]
+    names = [c["name"] for c in BENCH["configs"]]
+    named = [(f.relative_to(R.ROOT).as_posix(), n) for f in files
+             for n in names if n in f.read_text()]
+    assert len(files) > 10 and named == []
+
+
+@pytest.mark.parametrize("cfg", BENCH["configs"], ids=lambda c: c["name"])
+def test_config_files_state_a_cpu_size(cfg):
+    c = json.loads((R.ROOT / cfg["file"]).read_text())
+    h, w = c["cpu"]["shape"][-2:]
+    assert h % (1 << c["cpu"]["levels"]) == 0
+    assert w % (1 << c["cpu"]["levels"]) == 0
+    assert math.prod(c["cpu"]["shape"]) <= 1 << 16    # not a chip size

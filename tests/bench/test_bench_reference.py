@@ -45,6 +45,24 @@ def test_reference_refuses_unknown_wavelet_and_shape():
         R.dwt2(np.zeros((12, 16)), "cdf97", 3)
 
 
+def test_encode_check_level_shifts_integer_samples_only():
+    """ISO/IEC 15444-1 Annex G.1.2: an unsigned B-bit sample x enters
+    the transform as x - 2**(B-1); float samples arrive shifted."""
+    from bench.drivers import encode
+    u16 = np.array([[0, 1, 32767], [32768, 40000, 65535]], np.uint16)
+    got = encode.reference_input(u16, {"dtype": "float32",
+                                       "samples": "uint16", "bit_depth": 16})
+    assert got.dtype == np.float64
+    assert np.array_equal(got, [[-32768, -32767, -1], [0, 7232, 32767]])
+    u12 = np.array([0, 2048, 4095], np.uint16)
+    assert np.array_equal(encode.reference_input(
+        u12, {"dtype": "float32", "samples": "uint16", "bit_depth": 12}),
+        [-2048, 0, 2047])
+    f32 = np.array([-2048.0, 0.0, 2047.0], np.float32)
+    got = encode.reference_input(f32, {"dtype": "float32", "bit_depth": 12})
+    assert np.array_equal(got, f32) and got.dtype == np.float64
+
+
 def test_rel_err_reads_the_worst_gap_over_the_largest_value():
     want = [np.array([4.0, -8.0]), np.array([[2.0]])]
     got = [np.array([4.0, -8.5]), np.array([[2.25]])]
