@@ -131,20 +131,22 @@ def validate_finite(x, mode, what: str = "input") -> None:
 
 def _plan_for(shape, dtype, wavelet, levels, scheme, optimize, backend,
               fuse, boundary, compute_dtype, tap_opt, tiles=None,
-              packet=None, ndim=2):
+              packet=None, ndim=2, bit_depth=None):
     from repro import engine as E  # deferred: core <-> engine import cycle
     return E.get_plan(wavelet=wavelet, scheme=scheme, levels=levels,
                       shape=tuple(shape), dtype=str(dtype), backend=backend,
                       optimize=optimize, fuse=fuse, boundary=boundary,
                       compute_dtype=compute_dtype, tap_opt=tap_opt,
-                      tiles=tiles, packet=packet, ndim=ndim)
+                      tiles=tiles, packet=packet, ndim=ndim,
+                      bit_depth=bit_depth)
 
 
 def dwt2(x: jax.Array, wavelet: str = "cdf97", levels: int = 1,
          scheme: str = "ns-polyconv", optimize: bool = False,
          backend: str = "jnp", fuse: str = "none",
          boundary: str = "periodic", compute_dtype: str = "float32",
-         tap_opt: str = "full", tiles=None, validate=None) -> Pyramid:
+         tap_opt: str = "full", tiles=None, validate=None,
+         bit_depth=None) -> Pyramid:
     """Multi-level forward 2-D DWT of a (batch of) image(s) (..., H, W).
 
     H and W must be divisible by 2**levels.  Dispatches through the
@@ -167,6 +169,14 @@ def dwt2(x: jax.Array, wavelet: str = "cdf97", levels: int = 1,
     error instead of propagating garbage coefficients
     (:func:`validate_finite`).
 
+    Integer samples (an unsigned ``x``, e.g. ``uint16``) need
+    ``bit_depth``, their precision in bits: the transform then sees
+    ``x - 2**(bit_depth - 1)`` (the DC level shift of ISO/IEC 15444-1
+    Annex G.1.2), computed in ``compute_dtype``, and the coefficients
+    are float32.  Integer input without ``bit_depth``, or with one
+    larger than its dtype holds, raises a ``ValueError``; so does a
+    ``bit_depth`` with float input, which is taken as already shifted.
+
     >>> import jax.numpy as jnp
     >>> from repro.core import dwt2
     >>> img = jnp.ones((2, 16, 16))          # batch of 2, periodic 16x16
@@ -183,7 +193,8 @@ def dwt2(x: jax.Array, wavelet: str = "cdf97", levels: int = 1,
     x = jnp.asarray(x)
     validate_finite(x, validate, what="dwt2 input")
     plan = _plan_for(x.shape, x.dtype, wavelet, levels, scheme, optimize,
-                     backend, fuse, boundary, compute_dtype, tap_opt, tiles)
+                     backend, fuse, boundary, compute_dtype, tap_opt, tiles,
+                     bit_depth=bit_depth)
     return plan.execute(x)
 
 

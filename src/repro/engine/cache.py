@@ -88,6 +88,7 @@ def get_plan(*, wavelet: str = "cdf97", scheme: str = "ns-polyconv",
              compute_dtype: str = "float32", tap_opt: str = "full",
              tiles: Optional[Tuple[int, int]] = None,
              packet=None, ndim: int = 2,
+             bit_depth: Optional[int] = None,
              cache: Optional[PlanCache] = None) -> DwtPlan:
     """Fetch (or build) the plan for one transform configuration.
 
@@ -103,7 +104,8 @@ def get_plan(*, wavelet: str = "cdf97", scheme: str = "ns-polyconv",
     canonical leaf tuple — so every admissible spelling of the same
     tree shares one cached plan — and ``levels`` is overridden by the
     tree depth.  ``ndim=3`` keys the t+2D volume transform over
-    ``(..., T, H, W)``.
+    ``(..., T, H, W)``.  ``bit_depth`` is the precision of integer
+    samples (``dtype`` unsigned), which the forward level-shifts.
 
     >>> from repro.engine import PlanCache, get_plan
     >>> cache = PlanCache()
@@ -139,7 +141,8 @@ def get_plan(*, wavelet: str = "cdf97", scheme: str = "ns-polyconv",
                   tap_opt=tap_opt,
                   tiles=(None if tiles is None
                          else (int(tiles[0]), int(tiles[1]))),
-                  packet=packet, ndim=int(ndim))
+                  packet=packet, ndim=int(ndim),
+                  bit_depth=None if bit_depth is None else int(bit_depth))
     # explicit None check: an empty PlanCache is falsy (__len__ == 0)
     return (_GLOBAL if cache is None else cache).get(key)
 

@@ -121,13 +121,29 @@ def run_programs_jnp(programs, planes, compute_dtype):
     return tuple(p.astype(out_dtype) for p in cur)
 
 
+def samples_in(x, key):
+    """The forward's input as the transform sees it: integer samples
+    level-shifted (``polyphase.level_shift``), float input as it is."""
+    if not jnp.issubdtype(x.dtype, jnp.integer):
+        return x
+    return PP.level_shift(x, key.bit_depth, jnp.dtype(key.compute_dtype))
+
+
+def slices_to_planes(x, spec, key):
+    """The polyphase split of the jnp and xla forwards: strided slices,
+    after the level shift of integer samples."""
+    x = samples_in(x, key)
+    PP.count_split(x, "slices", spec.index)
+    return S.to_planes(x)
+
+
 # ---------------------------------------------------------------------------
 # jnp backend: periodic rolls over whole planes
 # ---------------------------------------------------------------------------
 
 def jnp_level_forward(x, spec, key):
     """One forward level: image (..., H, W) -> 4 planes (..., H/2, W/2)."""
-    planes = S.to_planes(x)
+    planes = slices_to_planes(x, spec, key)
     cdt = jnp.dtype(key.compute_dtype)
     if spec.fwd_programs is not None:
         return run_programs_jnp(spec.fwd_programs, planes, cdt)
@@ -155,7 +171,10 @@ def jnp_level_inverse(planes, spec, key):
 # ---------------------------------------------------------------------------
 
 def pallas_level_forward(x, spec, key):
-    planes = PP.to_planes(x)
+    bit_depth = key.bit_depth if jnp.issubdtype(x.dtype, jnp.integer) \
+        else None
+    planes = PP.to_planes(x, bit_depth, jnp.dtype(key.compute_dtype),
+                          level=spec.index)
     return PP.apply_steps_pallas(
         spec.fwd_steps, planes,
         fuse=("none" if key.fuse == "none" else "scheme"),
@@ -179,7 +198,7 @@ def pallas_level_inverse(planes, spec, key):
 # ---------------------------------------------------------------------------
 
 def xla_level_forward(x, spec, key):
-    planes = S.to_planes(x)
+    planes = slices_to_planes(x, spec, key)
     return CV.run_planes_conv(spec.fwd_programs, planes,
                               jnp.dtype(key.compute_dtype))
 
@@ -334,7 +353,7 @@ def make_pyramid_forward(plan):
     kw = _pyramid_kernel_kwargs(plan, False)
 
     def dwt_forward(x):
-        return PP.pyramid_forward_pallas(x, **kw)
+        return PP.pyramid_forward_pallas(samples_in(x, plan.key), **kw)
 
     fn = compiled_jit(dwt_forward)
 

@@ -7,7 +7,7 @@ products) on every ``dwt2`` call and re-decided block shapes on every
 ``pallas_call``.  A :class:`DwtPlan` does all of that exactly once per
 
     (wavelet, scheme, levels, shape, dtype, backend, optimize, fuse,
-     boundary)
+     boundary, ..., bit_depth)
 
 key: per-level :class:`~repro.kernels.polyphase.StepSpec` sequences
 (forward and inverse), per-level block shapes and halo pads, and the
@@ -62,6 +62,50 @@ FUSE_MODES = ("none", "scheme", "levels", "pyramid")
 BOUNDARIES = ("periodic",)
 COMPUTE_DTYPES = ("float32", "bfloat16")
 
+
+def coefficient_dtype(dtype: str) -> str:
+    """The dtype a forward transform of ``dtype`` samples writes:
+    float32 for integer samples (level-shifted), else ``dtype`` itself
+    (I/O stays in the input dtype)."""
+    integer = jnp.issubdtype(jnp.dtype(dtype), jnp.integer)
+    return "float32" if integer else dtype
+
+
+def validate_samples(key: "PlanKey") -> None:
+    """Integer samples need their precision, ``bit_depth``: the forward
+    transform sees ``x - 2**(bit_depth - 1)`` (the DC level shift of
+    ISO/IEC 15444-1 Annex G.1.2) and writes float32 coefficients.  Float
+    samples are taken as already shifted and take no ``bit_depth``."""
+    dt = jnp.dtype(key.dtype)
+    if not jnp.issubdtype(dt, jnp.integer):
+        if key.bit_depth is not None:
+            raise ValueError(
+                f"bit_depth={key.bit_depth} is the precision of integer "
+                f"samples; float input (dtype {dt.name}) is taken as "
+                f"already level-shifted: drop bit_depth")
+        return
+    if key.packet is not None or key.ndim == 3 or key.tiles is not None:
+        raise ValueError(
+            f"integer samples (dtype {dt.name}) are taken by the "
+            f"monolithic 2-D forward alone (dwt2 with bit_depth); packet, "
+            f"3-D and tiled transforms take float samples, level-shifted")
+    if key.bit_depth is None:
+        raise ValueError(
+            f"integer samples (dtype {dt.name}) need bit_depth, their "
+            f"precision in bits: dwt2(x, bit_depth=B) transforms "
+            f"x - 2**(B-1) into float32 coefficients")
+    if not jnp.issubdtype(dt, jnp.unsignedinteger):
+        raise ValueError(
+            f"integer samples of dtype {dt.name} are signed: the level "
+            f"shift (bit_depth) is for unsigned samples; pass signed "
+            f"samples as float")
+    bits = 8 * dt.itemsize
+    if not 1 <= key.bit_depth <= bits:
+        raise ValueError(
+            f"bit_depth={key.bit_depth} does not fit dtype {dt.name}: "
+            f"it holds 1 to {bits} bits")
+
+
 PYRAMID_VMEM_LIMIT_ENV = "REPRO_PYRAMID_VMEM_LIMIT"
 # budget for the rough estimate of PP.pyramid_vmem_bytes (I/O windows +
 # three compute windows).  Mosaic's real scoped VMEM runs ~5x over such
@@ -110,7 +154,8 @@ class PlanKey:
     """Everything that determines a compiled execution plan.
 
     ``compute_dtype`` is the in-kernel arithmetic dtype (I/O stays in the
-    array dtype); ``tap_opt`` is the tap-program compilation level
+    array dtype, but for integer samples: :func:`coefficient_dtype`);
+    ``tap_opt`` is the tap-program compilation level
     ("off" = raw matrix walk, "exact" = bit-preserving compilation,
     "full" = fold + CSE + rank-1 factorization).
     """
@@ -136,6 +181,9 @@ class PlanKey:
     # 2 = image (..., H, W); 3 = volume (..., T, H, W) — the t+2D
     # transform (1-D temporal lifting + 2-D per half-band, per level)
     ndim: int = 2
+    # precision of integer samples (``dtype`` an unsigned integer), which
+    # the forward level-shifts by 2**(bit_depth-1); None for float input
+    bit_depth: Optional[int] = None
 
 
 def max_feasible_levels(h: int, w: int) -> int:
@@ -319,7 +367,7 @@ def _resolve_level(index: int, h: int, w: int, key: PlanKey,
                    block_target: Tuple[int, int],
                    backend: "B.Backend") -> LevelSpec:
     hp, wp = h // 2, w // 2
-    th, tw = PP.tile(key.dtype, PP._default_interpret())
+    th, tw = PP.tile(coefficient_dtype(key.dtype), PP._default_interpret())
     bh, hp2 = PP._pick_block(hp, block_target[0], th)
     bw, wp2 = PP._pick_block(wp, block_target[1], tw)
     fwd_programs = inv_programs = None
@@ -382,7 +430,7 @@ def _resolve_pyramid(key: PlanKey, h: int, w: int,
     inv_sched = C.inverse_schedule(
         C.level_reaches(inv_steps, inv_programs, L), L)
     align = 1 << L
-    itemsize = jnp.dtype(key.dtype).itemsize
+    itemsize = jnp.dtype(coefficient_dtype(key.dtype)).itemsize
     cdt_size = jnp.dtype(key.compute_dtype).itemsize
     limit = pyramid_vmem_limit()
     target = (int(block_target[0]), int(block_target[1]))
@@ -462,6 +510,7 @@ def _build_plan(key: PlanKey,
                          f"available: {C.OPT_LEVELS}")
     if key.levels < 1:
         raise ValueError(f"levels must be >= 1, got {key.levels}")
+    validate_samples(key)
     demoted = None
     if key.ndim not in (2, 3):
         raise ValueError(f"ndim must be 2 or 3, got {key.ndim}")

@@ -51,6 +51,7 @@ from repro.core import poly as P
 from repro.core import optimize as O
 from repro.core import schemes as S
 from repro import compiler as C
+from repro import telemetry as T
 from repro.compiler import execute as CX
 
 def _default_interpret() -> bool:
@@ -404,29 +405,78 @@ def apply_steps_pallas(steps: Sequence[StepSpec], planes, *,
 #: name of the split kernel (``pallas_call``) in the device trace
 SPLIT_KERNEL = "dwt_split"
 
+#: which split each forward level took, counted once per level when a
+#: forward executable is traced (nothing is counted per call)
+SPLIT_PATHS = T.counter(
+    "repro_split_path_total",
+    "polyphase splits of traced forward levels, by path: the split "
+    "kernel or strided slices", labelnames=("path", "level"))
 
-def split_fits(x) -> bool:
-    """The shapes :func:`split_planes` takes: a 32-bit ``(..., H, W)``
-    with even H and W a multiple of 256 (each plane's rows a whole
-    number of 128-lane tiles)."""
+
+def count_split(x, path: str, level: int) -> None:
+    """Count one level's split (``path`` ``"kernel"`` or ``"slices"``)
+    while a forward executable is traced; eager calls are not counted."""
+    if isinstance(x, jax.core.Tracer):
+        SPLIT_PATHS.inc(path=path, level=level)
+
+
+def _shift(v, bit_depth: int, compute_dtype):
+    """``v - 2**(bit_depth - 1)`` computed in ``compute_dtype``, held as
+    float32 (exact for float32 compute and up to 24-bit samples).  Only
+    float32 arithmetic and casts, so the split kernel runs it too."""
+    cdt = jnp.dtype(compute_dtype)
+    if jnp.issubdtype(v.dtype, jnp.unsignedinteger) and v.dtype.itemsize < 4:
+        v = v.astype(jnp.int32)   # Mosaic casts no unsigned int to float
+    v = v.astype(jnp.float32)
+    if cdt != jnp.float32:
+        v = v.astype(cdt).astype(jnp.float32)
+    v = v - float(2 ** (bit_depth - 1))
+    if cdt != jnp.float32:
+        v = v.astype(cdt).astype(jnp.float32)
+    return v
+
+
+def level_shift(x: jax.Array, bit_depth: int, compute_dtype=jnp.float32):
+    """Integer samples as the transform sees them: ``x - 2**(bit_depth -
+    1)`` (the DC level shift of ISO/IEC 15444-1 Annex G.1.2), as float32,
+    one element-wise op under the ``dwt.level_shift`` scope."""
+    with jax.named_scope("dwt.level_shift"):
+        return _shift(x, bit_depth, compute_dtype)
+
+
+def split_fits(x, bit_depth: Optional[int] = None) -> bool:
+    """The shapes :func:`split_planes` takes: ``(..., H, W)`` with even H
+    and even W of at least 256 (one column block of 128 plane lanes);
+    32-bit samples, or with ``bit_depth`` unsigned 16-bit ones, which
+    the kernel level-shifts into float32 planes."""
     h, w = jnp.shape(x)[-2:]
-    return (jnp.dtype(x.dtype).itemsize == 4 and h % 2 == 0
-            and w % (2 * LANES) == 0)
+    dt = jnp.dtype(x.dtype)
+    if bit_depth is None:
+        fits_dtype = dt.itemsize == 4
+    else:
+        fits_dtype = (dt.itemsize == 2
+                      and jnp.issubdtype(dt, jnp.unsignedinteger))
+    return fits_dtype and h % 2 == 0 and w % 2 == 0 and w >= 2 * LANES
 
 
-def _split_kernel(x_ref, o00, o01, o10, o11, t_ref, *, chunks):
+def _split_kernel(x_ref, o00, o01, o10, o11, t_ref, *, chunks, shift):
     """One (2L, chunks * 2L) block of the image into four
     (L, chunks * L) blocks of the planes, L = 128.  Mosaic loads a
     sublane stride only from a 128-lane buffer and no lane stride at
     all, so each 128-lane column strip goes through ``t_ref``: rows
     split by a strided load; columns by a transpose, a strided load and
-    a transpose back."""
+    a transpose back.  ``shift`` (or None) maps a strip of samples to
+    the planes' dtype on its way into ``t_ref``.  A block past the
+    image's last column reads undefined samples into columns that are
+    never written back: output column k reads input columns 2k and
+    2k + 1 alone."""
     n = LANES
     outs = ((o00, o01), (o10, o11))
     for k in range(chunks):
         rows = ([], [])               # rows[i][c]: rows i::2 of strip c
         for c in range(2):
-            t_ref[...] = x_ref[0, :, pl.ds((2 * k + c) * n, n)]
+            strip = x_ref[0, :, pl.ds((2 * k + c) * n, n)]
+            t_ref[...] = strip if shift is None else shift(strip)
             for i in range(2):
                 rows[i].append(t_ref[pl.ds(i, n, stride=2), :])
         for i in range(2):
@@ -437,35 +487,58 @@ def _split_kernel(x_ref, o00, o01, o10, o11, t_ref, *, chunks):
                     t_ref[pl.ds(j, n, stride=2), :].T
 
 
-def to_planes(x: jax.Array):
+def to_planes(x: jax.Array, bit_depth: Optional[int] = None,
+              compute_dtype=jnp.float32, level: int = 0):
     """The polyphase split of a Pallas forward level: the split kernel
     where :func:`split_fits`, else :func:`repro.core.schemes.to_planes`
-    (strided slices); both under the ``dwt.to_planes`` scope."""
-    if not split_fits(x):
+    (strided slices); both under the ``dwt.to_planes`` scope.  Integer
+    samples (``bit_depth``) are level-shifted by the kernel as it reads
+    them, or else by :func:`level_shift` first.  The path taken counts
+    in ``repro_split_path_total`` (:func:`count_split`)."""
+    if bit_depth is not None and not split_fits(x, bit_depth):
+        x, bit_depth = level_shift(x, bit_depth, compute_dtype), None
+    if not split_fits(x, bit_depth):
+        count_split(x, "slices", level)
         return S.to_planes(x)
+    count_split(x, "kernel", level)
     with jax.named_scope("dwt.to_planes"):
-        return split_planes(x)
+        return split_planes(x, bit_depth, compute_dtype)
 
 
-def split_planes(x: jax.Array):
+def split_planes(x: jax.Array, bit_depth: Optional[int] = None,
+                 compute_dtype=jnp.float32):
     """``x[..., i::2, j::2]`` for (i, j) in (0,0), (0,1), (1,0), (1,1),
     bit for bit, as one Pallas kernel: one HBM read and one write of
-    the image (``split_fits(x)`` must hold).  A strided index or slice
-    of the lane axis is a gather or a per-element copy on the TPU."""
+    the image (``split_fits(x, bit_depth)`` must hold).  A strided index
+    or slice of the lane axis is a gather or a per-element copy on the
+    TPU.  With ``bit_depth`` the planes are the level-shifted samples
+    in float32 (:func:`level_shift`), computed as they are read.
+
+    Column blocks are ``chunks`` 256-column strips: as many as divide W
+    up to 4 where W is a multiple of 256, else 4 (fewer on a narrower
+    image) with the last block partial, as the last row block is."""
     *lead, h, w = x.shape
     xb = x.reshape(-1, h, w)
-    chunks = math.gcd(w // (2 * LANES), 4)
+    strips = w // (2 * LANES)
+    chunks = math.gcd(strips, 4) if w % (2 * LANES) == 0 \
+        else min(strips, 4)
     bw = 2 * LANES * chunks
-    plane = jax.ShapeDtypeStruct((xb.shape[0], h // 2, w // 2), x.dtype)
+    if bit_depth is None:
+        out_dtype, shift = x.dtype, None
+    else:
+        out_dtype = jnp.float32
+        shift = functools.partial(_shift, bit_depth=bit_depth,
+                                  compute_dtype=compute_dtype)
+    plane = jax.ShapeDtypeStruct((xb.shape[0], h // 2, w // 2), out_dtype)
     out_spec = pl.BlockSpec((1, LANES, bw // 2), lambda b, i, j: (b, i, j))
     planes = pl.pallas_call(
-        functools.partial(_split_kernel, chunks=chunks),
-        grid=(xb.shape[0], pl.cdiv(h // 2, LANES), w // bw),
+        functools.partial(_split_kernel, chunks=chunks, shift=shift),
+        grid=(xb.shape[0], pl.cdiv(h // 2, LANES), pl.cdiv(w, bw)),
         in_specs=[pl.BlockSpec((1, 2 * LANES, bw),
                                lambda b, i, j: (b, i, j))],
         out_specs=[out_spec] * 4,
         out_shape=[plane] * 4,
-        scratch_shapes=[pltpu.VMEM((2 * LANES, LANES), x.dtype)],
+        scratch_shapes=[pltpu.VMEM((2 * LANES, LANES), out_dtype)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * 3),
         interpret=_default_interpret(),

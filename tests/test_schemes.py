@@ -169,10 +169,34 @@ def test_split_kernel_is_the_strided_index_bit_for_bit(lead, dtype, hw):
     assert [_bits(g) for g in got] == [_bits(w) for w in want]
 
 
+@pytest.mark.parametrize("w", [260, 1372, 10980])
+@pytest.mark.parametrize("dtype", ["float32", "uint16"])
+@pytest.mark.parametrize("lead", [(), (3,), (2, 3)], ids=("2d", "c", "bc"))
+def test_split_kernel_takes_any_even_width_and_16_bit_samples(lead, dtype,
+                                                               w):
+    """Even widths that are not a multiple of 256 (the last column block
+    partial), and ``uint16`` samples level-shifted into float32 planes:
+    still exactly ``x[..., i::2, j::2]`` (of ``x - 2**15``)."""
+    from repro.kernels import polyphase as PP
+    shape = lead + (4, w)
+    if dtype == "uint16":
+        x = np.random.default_rng(0).integers(0, 1 << 16, shape).astype(
+            np.uint16)
+        bit_depth, base = 16, x.astype(np.float32) - 2.0 ** 15
+    else:
+        x = np.asarray(_seeded(shape, dtype))
+        bit_depth, base = None, x
+    assert PP.split_fits(x, bit_depth)
+    want = [base[..., i::2, j::2] for i in (0, 1) for j in (0, 1)]
+    got = PP.split_planes(jnp.asarray(x), bit_depth)
+    assert [_bits(g) for g in got] == [_bits(w) for w in want]
+
+
 @pytest.mark.parametrize("shape,dtype,kernel", [
     ((3, 36, 512), "float32", True),
     ((36, 256), "int32", True),
-    ((3, 36, 384), "float32", False),     # W not a multiple of 256
+    ((3, 36, 384), "float32", True),      # W not a multiple of 256
+    ((3, 36, 130), "float32", False),     # W under 256
     ((3, 35, 512), "float32", False),     # odd H
     ((3, 36, 512), "bfloat16", False),    # 16-bit
 ])

@@ -146,3 +146,52 @@ def test_xla_levels_compiles_for_v5e(inverse, one_chip, mosaic):
     text = _compile(plan, one_chip, inverse).as_text()
     # float32 operands reach the MXU unrounded (bf16 passes lose parity)
     assert "operand_precision={highest,highest}" in text
+
+
+S2_BAND = (10980, 10980)
+
+
+def test_uint16_band_forward_compiles_with_the_split_kernel(one_chip,
+                                                            mosaic):
+    """A Sentinel-2 L1C 10 m band (10980², ``uint16``, 2 levels) through
+    the Pallas forward: its split is the split kernel at both levels,
+    level 0 reading the 16-bit samples themselves (the level shift runs
+    in the kernel, no separate op), with no gather; one transform's
+    temporaries stay under 4 GB."""
+    import re
+    from repro.telemetry import scopes as SC
+    plan = PL.build_plan(PL.PlanKey("cdf97", "ns-polyconv", 2, S2_BAND,
+                                    "uint16", "pallas", False, "levels",
+                                    "periodic", bit_depth=16))
+    compiled = jax.jit(plan._forward).lower(jax.ShapeDtypeStruct(
+        S2_BAND, jnp.uint16, sharding=one_chip)).compile()
+    text = compiled.as_text()
+    assert " gather(" not in text
+    scopes = SC.parse(text)[1]
+    split = [op for op in scopes if op.startswith(PP.SPLIT_KERNEL)]
+    assert len(split) == 2
+    assert {scopes[op] for op in split} == {"dwt.to_planes"}
+    assert "dwt.level_shift" not in set(scopes.values())
+    assert re.search(rf"%{PP.SPLIT_KERNEL}[.\d]* = .*custom-call.*"
+                     rf"operand_layout_constraints={{u16\[1,10980,10980\]",
+                     text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e9
+
+
+@pytest.mark.parametrize("shape,grid,cols", [
+    ((3, 2160, 4096), (3, 9, 4), 1024), ((3, 1080, 2048), (3, 5, 2), 1024),
+    ((3, 540, 1024), (3, 3, 1), 1024), ((3, 270, 512), (3, 2, 1), 512)])
+def test_dci_split_keeps_its_grid_and_blocks(shape, grid, cols, one_chip,
+                                             mosaic):
+    """At each level of the DCI 4K cell the split is the kernel it was
+    with 256-multiple widths alone: the same grid and blocks."""
+    x = jax.ShapeDtypeStruct(shape, jnp.float32)
+    jaxpr = jax.make_jaxpr(PP.split_planes)(x)
+    eqn, = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    gm = eqn.params["grid_mapping"]
+    blocks = [tuple(getattr(b, "block_size", b) for b in bm.block_shape)
+              for bm in gm.block_mappings]
+    assert gm.grid == grid
+    assert blocks == [(1, 256, cols)] + [(1, 128, cols // 2)] * 4
+    jax.jit(PP.split_planes).lower(jax.ShapeDtypeStruct(
+        shape, jnp.float32, sharding=one_chip)).compile()
